@@ -193,6 +193,7 @@ def _spawn_client_procs(n_procs: int, map_wire: dict, duration_s: float,
         env = dict(os.environ)
         env["PYTHONPATH"] = os.path.join(_REPO_ROOT, "src") + os.pathsep \
             + env.get("PYTHONPATH", "")
+        env["JAX_PLATFORMS"] = "cpu"    # the parent may hold the chip
         procs.append(subprocess.Popen(
             [sys.executable, "-m", "benchmarks.distributed_serving",
              "--client-worker", json.dumps(args)],

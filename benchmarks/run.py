@@ -21,6 +21,9 @@ def main(argv=None):
     args = ap.parse_args(argv)
     os.makedirs(args.out, exist_ok=True)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks import (carbon, cost, distributed_serving, fused_plane,
                             ingest_throughput, online_adaptation,
                             prediction_error, profiling_time,

@@ -304,15 +304,15 @@ def _sweep_kernel(w_ref, order_ref, dep_ref, gb8_ref, ready0_ref, avail_ref,
         o = order_ref[t, 0]
         valid = o >= 0
         i = jnp.maximum(o, 0)
-        ready = pl.load(ready0_ref, (pl.ds(i, 1), slice(None)))[0]
+        ready = ready0_ref[pl.ds(i, 1), :][0]
         for k in range(D):                                    # unrolled
-            d = pl.load(dep_ref, (pl.ds(i, 1), pl.ds(k, 1)))[0, 0]
+            d = dep_ref[pl.ds(i, 1), pl.ds(k, 1)][0, 0]
             dv = d >= 0
             dsafe = jnp.maximum(d, 0)
-            dfin = pl.load(fin_ref, (pl.ds(dsafe, 1), pl.ds(0, 1)))[0, 0]
-            crow = pl.load(comm_ref, (pl.ds(dsafe, 1), slice(None)))[0]
+            dfin = fin_ref[pl.ds(dsafe, 1), pl.ds(0, 1)][0, 0]
+            crow = comm_ref[pl.ds(dsafe, 1), :][0]
             ready = jnp.where(dv, jnp.maximum(ready, dfin + crow), ready)
-        dur = pl.load(w_ref, (pl.ds(i, 1), slice(None)))[0]
+        dur = w_ref[pl.ds(i, 1), :][0]
         b0 = b0_ref[...]
         b1 = b1_ref[...]
         prev = jnp.where(first, ninf, jnp.roll(b1, 1, axis=1))
@@ -327,8 +327,8 @@ def _sweep_kernel(w_ref, order_ref, dep_ref, gb8_ref, ready0_ref, avail_ref,
         eftj = estj + jnp.min(jnp.where(iota_n == j, dur, inf))
         est_ins = jnp.where(valid, estj, inf)
         eft_ins = jnp.where(valid, eftj, inf)
-        b0j = pl.load(b0_ref, (pl.ds(j, 1), slice(None)))[0]
-        b1j = pl.load(b1_ref, (pl.ds(j, 1), slice(None)))[0]
+        b0j = b0_ref[pl.ds(j, 1), :][0]
+        b1j = b1_ref[pl.ds(j, 1), :][0]
         pos = (jnp.sum((b0j < est_ins).astype(jnp.int32))
                + jnp.sum(((b0j == est_ins) & (b1j < eft_ins))
                          .astype(jnp.int32)))
@@ -336,17 +336,16 @@ def _sweep_kernel(w_ref, order_ref, dep_ref, gb8_ref, ready0_ref, avail_ref,
         sb1 = jnp.where(first[0], ninf, jnp.roll(b1j, 1))
         nb0 = jnp.where(ar < pos, b0j, jnp.where(ar == pos, est_ins, sb0))
         nb1 = jnp.where(ar < pos, b1j, jnp.where(ar == pos, eft_ins, sb1))
-        pl.store(b0_ref, (pl.ds(j, 1), slice(None)), nb0[None, :])
-        pl.store(b1_ref, (pl.ds(j, 1), slice(None)), nb1[None, :])
+        b0_ref[pl.ds(j, 1), :] = nb0[None, :]
+        b1_ref[pl.ds(j, 1), :] = nb1[None, :]
         iw = jnp.where(valid, i, T)
-        pl.store(fin_ref, (pl.ds(iw, 1), pl.ds(0, 1)),
-                 eftj.reshape(1, 1))
+        fin_ref[pl.ds(iw, 1), pl.ds(0, 1)] = eftj.reshape(1, 1)
         crow_new = jnp.where(same_ref[j] != 0.0, 0.0,
                              gb8_ref[i, 0] / gbps_ref[j])
-        pl.store(comm_ref, (pl.ds(iw, 1), slice(None)), crow_new[None, :])
-        pl.store(assign_ref, (pl.ds(iw, 1), pl.ds(0, 1)), j.reshape(1, 1))
-        pl.store(est_ref, (pl.ds(iw, 1), pl.ds(0, 1)), estj.reshape(1, 1))
-        pl.store(eft_ref, (pl.ds(iw, 1), pl.ds(0, 1)), eftj.reshape(1, 1))
+        comm_ref[pl.ds(iw, 1), :] = crow_new[None, :]
+        assign_ref[pl.ds(iw, 1), pl.ds(0, 1)] = j.reshape(1, 1)
+        est_ref[pl.ds(iw, 1), pl.ds(0, 1)] = estj.reshape(1, 1)
+        eft_ref[pl.ds(iw, 1), pl.ds(0, 1)] = eftj.reshape(1, 1)
         return 0
 
     jax.lax.fori_loop(0, T, body, 0)

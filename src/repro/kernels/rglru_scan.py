@@ -37,8 +37,7 @@ def _rglru_kernel(a_ref, gx_ref, h0_ref, o_ref, carry, *, block_t: int):
     # sequential in time, state in VMEM
     def body(t, h):
         h = a[t] * h + gx[t]
-        pl.store(o_ref, (pl.dslice(0, 1), pl.dslice(t, 1), slice(None)),
-                 h[None, None])
+        o_ref[pl.ds(0, 1), pl.ds(t, 1), :] = h[None, None]
         return h
 
     h = jax.lax.fori_loop(0, block_t, body, carry[...])

@@ -38,7 +38,7 @@ decision plane itself is the hot path.  This module keeps it resident:
     engine (flat interval arrays, the portable fallback and parity
     oracle) and the `kernels.decision_plane.eft_sweep` jitted engine —
     the whole per-task insertion loop compiled into ONE dispatch (run in
-    float64 on the host via jax's x64 mode, float32 on device).  The jit
+    float64 via jax's x64 mode on `sweep_device()`).  The jit
     engine is an order of magnitude faster at fleet scale and remains
     bit-identical: the sweep contains no multi-term sums, so there is
     nothing for the compiler to reassociate.  `engine="auto"` picks by
@@ -68,7 +68,7 @@ from repro.store import compute
 from repro.workflow.dag import WorkflowDAG
 
 __all__ = ["FusedPlane", "PlaneStats", "ReplanRequest",
-           "fused_heft_schedule", "replan_many"]
+           "fused_heft_schedule", "replan_many", "sweep_device"]
 
 
 # ---------------------------------------------------------------------------
@@ -156,20 +156,6 @@ def _context(dag: WorkflowDAG, nodes: List[NodeSpec],
         while len(rank_cache) > _CTX_CACHE_MAX:    # bound replan-frontier
             rank_cache.pop(next(iter(rank_cache)))  # churn (FIFO evict)
     return ctx
-
-
-_HAVE_JIT: Optional[bool] = None
-
-
-def _jit_available() -> bool:
-    global _HAVE_JIT
-    if _HAVE_JIT is None:
-        try:
-            from repro.kernels import decision_plane  # noqa: F401
-            _HAVE_JIT = True
-        except Exception:       # pragma: no cover - jax is a hard dep here
-            _HAVE_JIT = False
-    return _HAVE_JIT
 
 
 class _SlotArrays:
@@ -293,8 +279,7 @@ def fused_heft_schedule(dag: WorkflowDAG, nodes: List[NodeSpec],
         W = matrix.costs(ctx.order, ctx.names, quantile=quantile)  # (T, N)
     rank = ctx.ranks(dag, W)
     if engine == "auto":
-        engine = ("jit" if W.size >= _JIT_MIN_CELLS and _jit_available()
-                  else "numpy")
+        engine = "jit" if W.size >= _JIT_MIN_CELLS else "numpy"
     if engine == "jit":
         return _schedule_jit(ctx, dag, nodes, W, rank, ready_at,
                              node_available)
@@ -408,18 +393,27 @@ def _build_schedule(ctx: _PlanContext, order_arr: np.ndarray,
     return sched
 
 
+def sweep_device():
+    """The device the float64 jit sweep runs on: the process's CPU device,
+    on every platform.  The sweep's contract is schedules bitwise equal to
+    `heft.heft_schedule_matrix`; a TPU emulates float64 and does not keep
+    it, so a process that holds a TPU places the sweep on its host CPU."""
+    import jax
+    return jax.devices("cpu")[0]
+
+
 def _schedule_jit(ctx: _PlanContext, dag: WorkflowDAG,
                   nodes: List[NodeSpec], W: np.ndarray,
                   rank: Dict[str, float], ready_at,
                   node_available: Optional[Dict[str, float]]) -> Schedule:
-    from jax.experimental import enable_x64
+    import jax
 
     from repro.kernels import decision_plane as dp
     packed = _sweep_inputs(ctx, dag, nodes, W, rank, ready_at,
                            node_available)
     while True:
         S = ctx.slot_cap
-        with enable_x64():
+        with jax.enable_x64(True), jax.default_device(sweep_device()):
             assign, est, eft, cnt = dp.eft_sweep(
                 *packed, ctx.same, ctx.gbps_min, S=S)
             assign = np.asarray(assign)
@@ -700,8 +694,7 @@ def _schedule_requests(requests: Sequence[ReplanRequest],
         mat, W = plane.cost_view(req.dag, req.quantile)
         ctx = _context(req.dag, plane.nodes, plane.rank_cache)
         rank = ctx.ranks(req.dag, W)
-        if not (fuse_sweeps and W.size >= _JIT_MIN_CELLS
-                and _jit_available()):
+        if not (fuse_sweeps and W.size >= _JIT_MIN_CELLS):
             results[pos] = fused_heft_schedule(
                 req.dag, plane.nodes, mat, ready_at=req.ready_at,
                 node_available=req.node_available, quantile=req.quantile,
@@ -722,7 +715,7 @@ def _schedule_requests(requests: Sequence[ReplanRequest],
 
 def _dispatch_group(members: list, results: List[Optional[Schedule]]
                     ) -> None:
-    from jax.experimental import enable_x64
+    import jax
 
     from repro.kernels import decision_plane as dp
     ctx0 = members[0][2]
@@ -730,7 +723,7 @@ def _dispatch_group(members: list, results: List[Optional[Schedule]]
                for k in range(6)]
     while True:
         S = max(m[2].slot_cap for m in members)
-        with enable_x64():
+        with jax.enable_x64(True), jax.default_device(sweep_device()):
             if len(members) == 1:
                 assign, est, eft, cnt = dp.eft_sweep(
                     *members[0][3], ctx0.same, ctx0.gbps_min, S=S)

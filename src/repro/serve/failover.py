@@ -191,7 +191,7 @@ class ShardSupervisor:
         env = dict(os.environ)
         src = os.path.join(self.repo_root, "src")
         env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env["JAX_PLATFORMS"] = "cpu"     # the parent may hold the chip
         return env
 
     def start(self, spec: ShardSpec, map_json: str) -> int:

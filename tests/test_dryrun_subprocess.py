@@ -14,7 +14,7 @@ ROOT = os.path.join(os.path.dirname(__file__), "..")
 def _run(args):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"     # placeholder CPU devices only
     return subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun"] + args,
         cwd=ROOT, env=env, capture_output=True, text=True, timeout=560)

@@ -127,7 +127,7 @@ def test_auto_engine_policy_is_size_based(monkeypatch):
 
 
 def test_upward_rank_kernel_matches_host_recurrence():
-    from jax.experimental import enable_x64
+    import jax
 
     from repro.kernels import decision_plane as dp
     from repro.sched.heft import comm_structure
@@ -153,7 +153,7 @@ def test_upward_rank_kernel_matches_host_recurrence():
                         / gbps_min).ravel().cumsum()[-1]) / n_nodes ** 2
          for u in order])
     w_avg = W.cumsum(axis=1)[:, -1] / n_nodes
-    with enable_x64():
+    with jax.enable_x64(True):
         got = np.asarray(dp.upward_rank(w_avg, avg_comm, succ_pad))
     want_arr = np.asarray([want[u] for u in order])
     assert np.array_equal(got, want_arr)
@@ -246,7 +246,7 @@ def test_replan_many_fuses_same_cluster_sweeps(monkeypatch):
 
 
 def test_eft_sweep_many_lanes_match_single():
-    from jax.experimental import enable_x64
+    import jax
 
     from repro.kernels import decision_plane as dp
     dag, nodes, svc = _build(30, 4, 17)
@@ -259,7 +259,7 @@ def test_eft_sweep_many_lanes_match_single():
         packs.append(fused_mod._sweep_inputs(ctx, dag, nodes, W, rank,
                                              None, None))
     stacked = [np.stack([p[k] for p in packs]) for k in range(6)]
-    with enable_x64():
+    with jax.enable_x64(True):
         many = dp.eft_sweep_many(*stacked, ctx.same, ctx.gbps_min, S=16)
         many = [np.asarray(a) for a in many]
         for b, p in enumerate(packs):
