@@ -1,0 +1,12 @@
+"""Share of the chip's roofline that the `bayes_predict` Pallas kernel
+reached over the window: the operations and bytes of the answered queries
+(padding excluded) over the kernel's device time in the trace."""
+from bench import roofline
+from bench.layers._shared import is_predict_kernel, kernel_roofline
+
+
+def read(ctx):
+    q = ctx["counters"].get("answered_queries", 0)
+    if not q:
+        return None
+    return kernel_roofline(ctx, is_predict_kernel, roofline.predict_cost(q))
