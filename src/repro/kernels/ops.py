@@ -8,6 +8,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels import ref
 from repro.kernels.bayes_fit import bayes_fit as _bayes_fit_pallas
 from repro.kernels.bayes_fit import bayes_predict as _bayes_predict_pallas
@@ -108,10 +109,12 @@ def bayes_predict(x, post, *, impl: str = "auto"):
     qp = -(-max(q, 1) // _PREDICT_TILE) * _PREDICT_TILE
     if qp != q:
         pad = qp - q
-        x = jnp.pad(x, (0, pad))
-        post = {k: jnp.pad(jnp.asarray(v),
-                           ((0, pad),) + ((0, 0),) * (jnp.ndim(v) - 1),
-                           constant_values=1.0 if k in _SAFE_ONE else 0.0)
-                for k, v in post.items()}
+        with obs.span("lotaru.compute.pad"):
+            x = jnp.pad(x, (0, pad))
+            post = {k: jnp.pad(jnp.asarray(v),
+                               ((0, pad),) + ((0, 0),) * (jnp.ndim(v) - 1),
+                               constant_values=1.0 if k in _SAFE_ONE else 0.0)
+                    for k, v in post.items()}
     mean, std = _bayes_predict_jit(x, post, impl)
-    return mean[:q], std[:q]
+    with obs.span("lotaru.compute.pad"):
+        return mean[:q], std[:q]

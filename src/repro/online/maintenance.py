@@ -34,11 +34,13 @@ loop next to its batch-window worker.
 from __future__ import annotations
 
 import contextlib
+import itertools
 import threading
 import time
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
+from repro import obs
 from repro.store.posterior import PosteriorStore, TenantBinding
 
 
@@ -105,6 +107,7 @@ class FleetRefresher:
         self.failure_count = 0           # background passes that raised
         self.last_error: Optional[BaseException] = None   # most recent one
         self._last_refresh: Dict[Tuple[int, str], float] = {}   # applied-at
+        self._pass_ids = itertools.count()       # trace ids of passes
         self._stop = threading.Event()                          # monotonic
         self._thread: Optional[threading.Thread] = None
 
@@ -118,25 +121,27 @@ class FleetRefresher:
         `min_interval_s` are not yet due, and each tenant contributes at
         most `max_tasks_per_tenant_per_cycle` tasks per sweep (the rest
         remain due and surface on later sweeps — deferred, not dropped)."""
-        out = []
-        pol = self.policy
-        now = time.monotonic()
-        per_tenant: Dict[str, int] = {}
-        for b in self.store.bindings():
-            fn = getattr(b.predictor, "refresh_due", None)
-            if fn is None:
-                continue
-            for t in fn(pol):
-                if pol.min_interval_s is not None:
-                    last = self._last_refresh.get((id(b.predictor), t))
-                    if last is not None and now - last < pol.min_interval_s:
-                        continue
-                if pol.max_tasks_per_tenant_per_cycle is not None:
-                    n = per_tenant.get(b.tenant, 0)
-                    if n >= pol.max_tasks_per_tenant_per_cycle:
-                        continue
-                    per_tenant[b.tenant] = n + 1
-                out.append((b, t))
+        with obs.span("lotaru.refresh.due"):
+            out = []
+            pol = self.policy
+            now = time.monotonic()
+            per_tenant: Dict[str, int] = {}
+            for b in self.store.bindings():
+                fn = getattr(b.predictor, "refresh_due", None)
+                if fn is None:
+                    continue
+                for t in fn(pol):
+                    if pol.min_interval_s is not None:
+                        last = self._last_refresh.get((id(b.predictor), t))
+                        if (last is not None
+                                and now - last < pol.min_interval_s):
+                            continue
+                    if pol.max_tasks_per_tenant_per_cycle is not None:
+                        n = per_tenant.get(b.tenant, 0)
+                        if n >= pol.max_tasks_per_tenant_per_cycle:
+                            continue
+                        per_tenant[b.tenant] = n + 1
+                    out.append((b, t))
         return out
 
     # ---- the batched refresh pass -------------------------------------------
@@ -145,11 +150,35 @@ class FleetRefresher:
         """Refresh every due task in ONE batched fit dispatch and publish
         all rewritten rows in ONE store generation.  See module docstring
         for the race/locking story."""
-        from repro.kernels.bayes_fit import pad_ragged
         from repro.store.compute import fit_stacked
         t0 = time.perf_counter()
-        if due is None:
-            due = self.due()
+        with obs.span("lotaru.refresh.pass", pass_id=next(self._pass_ids)):
+            if due is None:
+                due = self.due()
+            with obs.span("lotaru.refresh.prepare"):
+                rows, keys, padded = self._prepare(due)
+            n_rows = n_tenants = n_stale = n_dispatches = 0
+            if rows:
+                # ONE padded/masked evidence fixed-point dispatch for the
+                # fleet
+                with obs.span("lotaru.refresh.fit"):
+                    post = fit_stacked(*padded, impl=self.impl)
+                self.dispatch_count += 1
+                n_dispatches = 1
+                with obs.span("lotaru.refresh.apply"):
+                    n_rows, n_tenants, n_stale = self._apply(rows, keys, post)
+        report = RefreshReport(n_tasks=n_rows, n_tenants=n_tenants,
+                               n_dispatches=n_dispatches, n_stale=n_stale,
+                               generation=self.store.generation,
+                               duration_s=time.perf_counter() - t0)
+        self._record(report)
+        return report
+
+    def _prepare(self, due):
+        """The due tasks' fit rows: their observation buffers, snapshotted,
+        and the padded (x, y, mask) arrays of the one fit (None when
+        nothing is due)."""
+        from repro.kernels.bayes_fit import pad_ragged
         # one fit row per distinct (predictor, task): two bindings may feed
         # the same predictor into two namespaces — fit once, publish to both.
         # Buffers are snapshotted in ONE refresh_snapshot call per predictor
@@ -168,18 +197,18 @@ class FleetRefresher:
             for task, (seq, x, y) in p.refresh_snapshot(tasks).items():
                 rows[(id(p), task)].update(seq=seq, x=x, y=y)
         if not rows:
-            report = RefreshReport(generation=self.store.generation,
-                                   duration_s=time.perf_counter() - t0)
-            self._record(report)
-            return report
-
-        # ONE padded/masked evidence fixed-point dispatch for the fleet
+            return rows, [], None
         keys = list(rows)
         x, y, m = pad_ragged([rows[k]["x"] for k in keys],
                              [rows[k]["y"] for k in keys])
-        post = fit_stacked(x, y, m, impl=self.impl)
-        self.dispatch_count += 1
+        if obs.enabled():
+            obs.count("lotaru.refresh.fit_cells", m.size)
+            obs.count("lotaru.refresh.fit_points", int(m.sum()))
+        return rows, keys, (x, y, m)
 
+    def _apply(self, rows, keys, post) -> Tuple[int, int, int]:
+        """Moment-match the fitted posteriors into the streaming states and
+        publish them -> (rows published, tenants, stale fits)."""
         # moment-match back into the streaming states; a task whose change
         # seq moved while the fit ran keeps its (newer) state and stays due
         applied: List[dict] = []
@@ -225,13 +254,7 @@ class FleetRefresher:
             for b in bindings:
                 if not b._detached:
                     b._advance_cursor(per_binding.get(id(b), {}))
-
-        report = RefreshReport(n_tasks=n_rows, n_tenants=len(tenants),
-                               n_dispatches=1, n_stale=n_stale,
-                               generation=self.store.generation,
-                               duration_s=time.perf_counter() - t0)
-        self._record(report)
-        return report
+        return n_rows, len(tenants), n_stale
 
     def _record(self, report: RefreshReport) -> None:
         if len(self.reports) >= 4096:    # telemetry, not a log: a daemon

@@ -14,6 +14,8 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from repro import obs
+
 # the posterior leaves the serving stack stores and gathers, with their
 # per-row shapes ('n' is fit metadata, not needed by the predictive)
 LEAVES = ("mu", "sigma", "beta_prec", "x_mu", "x_sd", "y_mu", "y_sd")
@@ -32,13 +34,21 @@ def predict_stacked(x: np.ndarray, post: dict, impl: str = "auto"
     that never predict."""
     from repro.core import bayes
     from repro.kernels import ops
-    if impl in ("pallas", "interpret") or (impl == "auto" and ops._on_tpu()):
+    with obs.span("lotaru.compute.predict"):
+        if not (impl in ("pallas", "interpret")
+                or (impl == "auto" and ops._on_tpu())):
+            return bayes.predict_blr_np(post, np.asarray(x, np.float64))
         import jax.numpy as jnp
+        x_j = jnp.asarray(x, jnp.float32)
         post_j = {k: jnp.asarray(v) for k, v in post.items()}
-        mean, std = ops.bayes_predict(jnp.asarray(x, jnp.float32), post_j,
-                                      impl=impl)
-        return np.asarray(mean, np.float64), np.asarray(std, np.float64)
-    return bayes.predict_blr_np(post, np.asarray(x, np.float64))
+        mean, std = ops.bayes_predict(x_j, post_j, impl=impl)
+        with obs.span("lotaru.compute.readback"):
+            out = np.asarray(mean, np.float64), np.asarray(std, np.float64)
+        if obs.enabled():
+            obs.count("lotaru.compute.h2d_bytes", x_j.nbytes + sum(
+                v.nbytes for v in post_j.values()))
+            obs.count("lotaru.compute.d2h_bytes", mean.nbytes + std.nbytes)
+        return out
 
 
 def fit_stacked(x: np.ndarray, y: np.ndarray, mask: np.ndarray,

@@ -24,6 +24,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.store.compute import finalize, predict_stacked
 from repro.store.keys import DEFAULT_TENANT, DEFAULT_WORKFLOW, namespace_str
 from repro.store.posterior import PosteriorStore, TenantBinding
@@ -73,7 +74,11 @@ class AsyncPredictionFrontend:
         self.dispatch_count = 0          # kernel dispatches issued
         self.coalesced: List[int] = []   # callers coalesced per dispatch
                                          # (bounded: recent dispatches only)
-        self._pending: List[Tuple[TenantBinding, list, Future]] = []
+        self.failure_count = 0           # flushes that raised past the
+        self.last_error: Optional[BaseException] = None  # per-caller guards
+        # (binding, queries, future, enqueue stamp while tracing)
+        self._pending: List[Tuple[TenantBinding, list, Future,
+                                  Optional[float]]] = []
         self._cv = threading.Condition()
         self._closed = False
         self._worker: Optional[threading.Thread] = None
@@ -109,7 +114,7 @@ class AsyncPredictionFrontend:
                     f"{len(self._pending)} caller batches already queued "
                     f"(max_pending_batches={self.max_pending_batches}); "
                     f"retry after the next flush")
-            self._pending.append((binding, queries, fut))
+            self._pending.append((binding, queries, fut, obs.stamp()))
             self._cv.notify()
         return fut
 
@@ -128,15 +133,34 @@ class AsyncPredictionFrontend:
         caller batches answered.  Failures are isolated per caller: a bad
         task name (or a namespace whose sync fails) rejects only the
         offending callers' futures — the shared dispatch still answers
-        everyone else."""
+        everyone else.  A failure outside those guards fails every caller
+        of the batch still waiting, counts in `failure_count` and
+        `last_error`, and is raised."""
         with self._cv:
             batch, self._pending = self._pending, []
         if not batch:
             return 0
+        if obs.enabled():
+            obs.since("lotaru.frontend.queue", [t for *_, t in batch])
+        with obs.span("lotaru.frontend.flush", dispatch=self.dispatch_count):
+            try:
+                self._serve(batch)
+            except Exception as e:                # noqa: BLE001
+                self.failure_count += 1
+                self.last_error = e
+                for _, _, fut, _ in batch:
+                    if not fut.done():
+                        _safe_set(fut, exc=e)
+                raise
+        return len(batch)
+
+    def _serve(self, batch: list) -> None:
+        """One dispatch for the taken batch; every caller's future is
+        resolved unless an error escapes."""
         # sync each distinct namespace once; a failing sync fails only the
         # callers of that namespace
         sync_err: dict = {}
-        for binding in {id(b): b for b, _, _ in batch}.values():
+        for binding in {id(b): b for b, *_ in batch}.values():
             try:
                 binding.sync()
                 sync_err[id(binding)] = None
@@ -144,7 +168,7 @@ class AsyncPredictionFrontend:
                 sync_err[id(binding)] = e
         snap = self.store.snapshot()
         valid = []
-        for binding, qs, fut in batch:
+        for binding, qs, fut, _ in batch:
             err = sync_err[id(binding)]
             if err is None:
                 try:                 # resolve this caller's keys up front so
@@ -158,7 +182,7 @@ class AsyncPredictionFrontend:
                 continue
             valid.append((binding, qs, keys, fut))
         if not valid:
-            return len(batch)
+            return
         try:
             x = np.asarray([q.input_gb for _, qs, _, _ in valid for q in qs])
             post = snap.gather([k for _, _, ks, _ in valid for k in ks])
@@ -170,7 +194,7 @@ class AsyncPredictionFrontend:
         except Exception as e:                    # noqa: BLE001
             for _, _, _, fut in valid:
                 _safe_set(fut, exc=e)
-            return len(batch)
+            return
         i = 0
         for binding, qs, _, fut in valid:
             j = i + len(qs)
@@ -182,7 +206,6 @@ class AsyncPredictionFrontend:
             else:
                 _safe_set(fut, result=out)
             i = j
-        return len(batch)
 
     def _loop(self) -> None:
         while True:
@@ -191,11 +214,12 @@ class AsyncPredictionFrontend:
                     self._cv.wait()
                 if self._closed and not self._pending:
                     return
-            time.sleep(self.window_s)    # the batch window: let concurrent
-            try:                         # callers pile into this dispatch
+            with obs.span("lotaru.frontend.window"):
+                time.sleep(self.window_s)   # the batch window: let concurrent
+            try:                            # callers pile into this dispatch
                 self.flush()
-            except Exception:            # noqa: BLE001  (a flush bug fails
-                pass                     # its futures; never the worker)
+            except Exception:   # noqa: BLE001  flush failed its callers and
+                pass            # counted the error; the worker lives on
 
     # ---- lifecycle ----------------------------------------------------------
     def close(self) -> None:

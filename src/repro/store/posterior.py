@@ -42,6 +42,7 @@ from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.store.compute import LEAF_SHAPES, LEAVES
 from repro.store.keys import (DEFAULT_TENANT, DEFAULT_WORKFLOW, SEP, TaskKey,
                               namespace_str, resolve_bench)
@@ -188,6 +189,10 @@ class StoreSnapshot:
         Rows are resolved block-by-block: with one block this is a single
         fancy index per leaf; with a sharded stack each block is touched at
         most once."""
+        with obs.span("lotaru.store.gather"):
+            return self._gather(keys)
+
+    def _gather(self, keys: Sequence) -> Dict[str, np.ndarray]:
         rows = np.asarray([self.row_of(k) for k in keys], np.int64)
         bids, slots = np.divmod(rows, self._block_size)
         out = {}
@@ -200,8 +205,9 @@ class StoreSnapshot:
         return out
 
     def get(self, key) -> Dict[str, np.ndarray]:
-        """One row's leaves (copies), as a predict_blr-compatible dict."""
-        g = self.gather([key])
+        """One row's leaves (copies), as a predict_blr-compatible dict.
+        Not a traced gather: callers read rows one by one in loops."""
+        g = self._gather([key])
         return {leaf: v[0] for leaf, v in g.items()}
 
     def rows_changed_since(self, keys: Sequence, generation: int

@@ -322,6 +322,41 @@ def test_frontend_failure_isolated_to_offending_caller():
                                   svc.predict_batch(good_qs))
 
 
+def test_frontend_failure_outside_caller_guards_fails_the_batch(
+        monkeypatch):
+    """an error no per-caller guard catches (here the store's snapshot)
+    fails every caller of the taken batch instead of leaving their futures
+    unresolved, and is counted; the auto-flush worker lives on."""
+    benches = _benches()
+    store = PosteriorStore()
+    svc = PredictionService(_fit(("bwa", "idx")), benches, store=store,
+                            tenant="a", workflow="w")
+    qs = _queries(["bwa"], [None, "N1"])
+    boom = RuntimeError("snapshot failed")
+
+    def failing_snapshot():
+        raise boom
+
+    real = store.snapshot
+    monkeypatch.setattr(store, "snapshot", failing_snapshot)
+    fe = AsyncPredictionFrontend(store, auto_flush=False)
+    futs = [fe.predict_async(qs, tenant="a", workflow="w") for _ in range(2)]
+    with pytest.raises(RuntimeError, match="snapshot failed"):
+        fe.flush()
+    for f in futs:
+        assert f.exception(timeout=5) is boom
+    assert fe.failure_count == 1 and fe.last_error is boom
+
+    with AsyncPredictionFrontend(store, window_s=0.001) as auto:
+        f = auto.predict_async(qs, tenant="a", workflow="w")
+        assert f.exception(timeout=10) is boom
+        assert auto.failure_count == 1 and auto.last_error is boom
+        monkeypatch.setattr(store, "snapshot", real)
+        np.testing.assert_array_equal(
+            auto.predict_async(qs, tenant="a", workflow="w").result(
+                timeout=10), svc.predict_batch(qs))
+
+
 def test_save_preserves_unresumed_namespace_state(tmp_path):
     """restore two tenants, resume only one, save again: the unresumed
     tenant's checkpointed streaming state must survive the second save."""
