@@ -35,6 +35,7 @@ beyond tolerance exits non-zero without it.  No child process is started.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -57,7 +58,8 @@ from benchmarks.ingest_throughput import _fleet, _stream  # noqa: E402
 from benchmarks.replan_latency import _build  # noqa: E402
 from repro.core import bayes  # noqa: E402
 from repro.kernels import ops  # noqa: E402
-from repro.kernels.bayes_fit import bayes_fit_ragged, pad_ragged  # noqa: E402
+from repro.kernels.bayes_fit import (bayes_fit_ragged,  # noqa: E402
+                                     pack_predict_planes, pad_ragged)
 from repro.online import (FleetRefresher, OnlinePredictor,  # noqa: E402
                           PredictionQuery, PredictionService, RefreshPolicy,
                           TaskCompletion)
@@ -302,8 +304,8 @@ def phase_predict(fleet: Fleet, sizes: Sizes, seed: int) -> dict:
     err = max(_rel_err(got[:, 0], mean, post["y_sd"] * f),
               _rel_err(got_std, std))
     pallas = _lowers_to_pallas(
-        ops.bayes_predict, jnp.asarray(x, jnp.float32),
-        {k: jnp.asarray(v, jnp.float32) for k, v in post.items()})
+        functools.partial(ops._bayes_predict_jit, impl="auto"),
+        jnp.asarray(pack_predict_planes(x, post)))
     n_q = len(x)
     return {"queries_per_dispatch": n_q, "callers": CALLERS,
             "dispatches": dispatches, "pallas": pallas,

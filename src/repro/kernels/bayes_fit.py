@@ -134,7 +134,7 @@ def pad_ragged(xs, ys, min_cols: int = 2, col_bucket: int = 64):
     N is rounded up to a `col_bucket` multiple: successive refresh passes
     see steadily-growing buffers, and without shape bucketing every pass
     would re-jit the batched fit for a new N (the same trick as the
-    predict path's _PREDICT_TILE)."""
+    predict path's PREDICT_TILE)."""
     t = len(xs)
     n = max(min_cols, max((len(v) for v in xs), default=min_cols))
     if col_bucket > 1:
@@ -318,64 +318,88 @@ def nig_fold_scan(xs, ys, mask, mu, v, prec, b):
 # elementwise in the query dimension — means and stds for tens of thousands
 # of (task, node, input) requests come back in a single fused pass instead
 # of one predict_blr dispatch per query.  Queries are laid out (rows, 128)
-# to match the fp32 VPU lane width.
+# to match the fp32 VPU lane width, and every input of a query rides in one
+# packed (PREDICT_PLANES, rows, 128) array: one transfer, one kernel input.
 
 LANE = 128
 DEFAULT_BLOCK_ROWS = 8
+PREDICT_TILE = LANE * DEFAULT_BLOCK_ROWS     # queries per grid step
+# one float32 plane per scalar of a query: its input size, then its gathered
+# posterior (mu, the sigma triangle, beta_prec, the x/y standardization)
+PREDICT_PLANES = 11
+# padded lanes: unit precision and scales keep the math finite
+PLANE_PAD = (0.0, 0.0, 0.0, 0.0, 0.0, 0.0, 1.0, 0.0, 1.0, 0.0, 1.0)
 
 
-def _predict_kernel(x_ref, mu_ref, sig_ref, beta_ref, stat_ref,
-                    mean_ref, std_ref):
-    x = x_ref[...]                                   # (br, LANE)
-    mu1 = mu_ref[0]                                  # component planes
-    mu2 = mu_ref[1]
-    s11, s12, s22 = sig_ref[0], sig_ref[1], sig_ref[2]
-    x_mu, x_sd = stat_ref[0], stat_ref[1]
-    y_mu, y_sd = stat_ref[2], stat_ref[3]
+def _predict_kernel(p_ref, out_ref):
+    x = p_ref[0]                                     # (br, LANE)
+    mu1 = p_ref[1]                                   # component planes
+    mu2 = p_ref[2]
+    s11, s12, s22 = p_ref[3], p_ref[4], p_ref[5]
+    beta = p_ref[6]
+    x_mu, x_sd = p_ref[7], p_ref[8]
+    y_mu, y_sd = p_ref[9], p_ref[10]
 
     xs = (x - x_mu) / x_sd
     mean_s = mu1 + mu2 * xs
-    var_s = 1.0 / beta_ref[...] + s11 + 2.0 * s12 * xs + s22 * xs * xs
-    mean_ref[...] = mean_s * y_sd + y_mu
-    std_ref[...] = jnp.sqrt(jnp.maximum(var_s, 0.0)) * y_sd
+    var_s = 1.0 / beta + s11 + 2.0 * s12 * xs + s22 * xs * xs
+    out_ref[0] = mean_s * y_sd + y_mu
+    out_ref[1] = jnp.sqrt(jnp.maximum(var_s, 0.0)) * y_sd
 
 
-def bayes_predict(x: jnp.ndarray, post: dict, *,
-                  block_rows: int = DEFAULT_BLOCK_ROWS,
-                  interpret: bool = False):
-    """x: (Q,) query inputs; post: posterior dict with leading dim Q
-    (already gathered per query).  Returns (mean, std), each (Q,)."""
-    q = x.shape[0]
-    tile = LANE * block_rows
-    qp = -(-q // tile) * tile
-    rows = qp // LANE
+def predict_plane_rows(x, post) -> tuple:
+    """The PREDICT_PLANES rows of the packed layout, in order, each (Q,):
+    views of x (Q,) and the leaves gathered per query (Q, ...)."""
+    mu, sig = post["mu"], post["sigma"]
+    return (x, mu[:, 0], mu[:, 1], sig[:, 0, 0], sig[:, 0, 1], sig[:, 1, 1],
+            post["beta_prec"], post["x_mu"], post["x_sd"], post["y_mu"],
+            post["y_sd"])
 
-    def pad(v):
-        v = jnp.asarray(v, jnp.float32)
-        return jnp.pad(v, (0, qp - q)).reshape(rows, LANE)
 
-    xq = pad(x)
-    mu = jnp.stack([pad(post["mu"][:, 0]), pad(post["mu"][:, 1])])
-    sig = jnp.stack([pad(post["sigma"][:, 0, 0]),
-                     pad(post["sigma"][:, 0, 1]),
-                     pad(post["sigma"][:, 1, 1])])
-    # padded lanes: beta=1, x_sd=1, y_sd=1 keep the math finite
-    beta = pad(post["beta_prec"]) + (1.0 - pad(jnp.ones((q,))))
-    stat = jnp.stack([pad(post["x_mu"]),
-                      pad(post["x_sd"]) + (1.0 - pad(jnp.ones((q,)))),
-                      pad(post["y_mu"]),
-                      pad(post["y_sd"]) + (1.0 - pad(jnp.ones((q,))))])
+def pack_predict_planes(x: np.ndarray, post: dict) -> np.ndarray:
+    """Host side of the predictive: NumPy x (Q,) and gathered leaves ->
+    a fresh float32 (PREDICT_PLANES, rows, LANE) buffer, Q padded up to a
+    PREDICT_TILE multiple with PLANE_PAD, ready for one transfer.  The
+    bucket keeps one compiled program for every Q up to the tile."""
+    q = len(x)
+    qp = -(-max(q, 1) // PREDICT_TILE) * PREDICT_TILE
+    buf = np.empty((PREDICT_PLANES, qp), np.float32)
+    for i, row in enumerate(predict_plane_rows(x, post)):
+        buf[i, :q] = row
+    buf[:, q:] = np.asarray(PLANE_PAD, np.float32)[:, None]
+    return buf.reshape(PREDICT_PLANES, qp // LANE, LANE)
 
-    grid = (rows // block_rows,)
-    row_spec = pl.BlockSpec((block_rows, LANE), lambda i: (i, 0))
-    plane = lambda k: pl.BlockSpec((k, block_rows, LANE), lambda i: (0, i, 0))
-    mean, std = pl.pallas_call(
+
+def bayes_predict_planes(planes: jnp.ndarray, *, interpret: bool = False):
+    """Packed planes (PREDICT_PLANES, rows, LANE), rows a DEFAULT_BLOCK_ROWS
+    multiple -> (2, rows, LANE) float32: mean, then std."""
+    n_planes, rows, lane = planes.shape
+    assert n_planes == PREDICT_PLANES and lane == LANE, planes.shape
+    assert rows % DEFAULT_BLOCK_ROWS == 0, rows
+    spec = lambda k: pl.BlockSpec((k, DEFAULT_BLOCK_ROWS, LANE),
+                                  lambda i: (0, i, 0))
+    return pl.pallas_call(
         _predict_kernel,
-        grid=grid,
-        in_specs=[row_spec, plane(2), plane(3), row_spec, plane(4)],
-        out_specs=[row_spec, row_spec],
-        out_shape=[jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
-                   jax.ShapeDtypeStruct((rows, LANE), jnp.float32)],
+        grid=(rows // DEFAULT_BLOCK_ROWS,),
+        in_specs=[spec(PREDICT_PLANES)],
+        out_specs=spec(2),
+        out_shape=jax.ShapeDtypeStruct((2, rows, LANE), jnp.float32),
         interpret=interpret,
-    )(xq, mu, sig, beta, stat)
-    return mean.reshape(-1)[:q], std.reshape(-1)[:q]
+    )(planes)
+
+
+def bayes_predict(x: jnp.ndarray, post: dict, *, interpret: bool = False):
+    """x: (Q,) query inputs; post: posterior dict with leading dim Q
+    (already gathered per query).  Returns (mean, std), each (Q,).  Stacks
+    and pads the planes with jnp (traceable), then the planes form."""
+    q = x.shape[0]
+    qp = -(-q // PREDICT_TILE) * PREDICT_TILE
+    rows = jnp.stack([jnp.asarray(r, jnp.float32)
+                      for r in predict_plane_rows(x, post)])
+    pad = jnp.broadcast_to(jnp.asarray(PLANE_PAD, jnp.float32)[:, None],
+                           (PREDICT_PLANES, qp - q))
+    planes = jnp.concatenate([rows, pad], axis=1)
+    out = bayes_predict_planes(
+        planes.reshape(PREDICT_PLANES, qp // LANE, LANE), interpret=interpret)
+    out = out.reshape(2, qp)
+    return out[0, :q], out[1, :q]

@@ -26,7 +26,8 @@ LEAF_SHAPES = {"mu": (2,), "sigma": (2, 2), "beta_prec": (), "x_mu": (),
 def predict_stacked(x: np.ndarray, post: dict, impl: str = "auto"
                     ) -> Tuple[np.ndarray, np.ndarray]:
     """(Q,) inputs + per-query gathered leaves (Q, ...) -> (mean, std) in
-    float64.  TPU: fused Pallas pass; elsewhere the vectorized float64
+    float64.  TPU: fused Pallas pass, fed from one host-packed buffer
+    (`kernels.ops.bayes_predict`); elsewhere the vectorized float64
     reference (bit-exact vs the scalar path at any runtime magnitude).
 
     jax/kernels are imported per call so `repro.store` (and the event
@@ -38,17 +39,7 @@ def predict_stacked(x: np.ndarray, post: dict, impl: str = "auto"
         if not (impl in ("pallas", "interpret")
                 or (impl == "auto" and ops._on_tpu())):
             return bayes.predict_blr_np(post, np.asarray(x, np.float64))
-        import jax.numpy as jnp
-        x_j = jnp.asarray(x, jnp.float32)
-        post_j = {k: jnp.asarray(v) for k, v in post.items()}
-        mean, std = ops.bayes_predict(x_j, post_j, impl=impl)
-        with obs.span("lotaru.compute.readback"):
-            out = np.asarray(mean, np.float64), np.asarray(std, np.float64)
-        if obs.enabled():
-            obs.count("lotaru.compute.h2d_bytes", x_j.nbytes + sum(
-                v.nbytes for v in post_j.values()))
-            obs.count("lotaru.compute.d2h_bytes", mean.nbytes + std.nbytes)
-        return out
+        return ops.bayes_predict(x, post, impl=impl)
 
 
 def fit_stacked(x: np.ndarray, y: np.ndarray, mask: np.ndarray,

@@ -100,8 +100,9 @@ def test_two_threads_record_at_once():
 
 def test_one_frontend_dispatch(tracing):
     """Three caller batches, one manual flush: three queue waits, one each
-    of flush, gather and predict, and the float32 bytes of the inputs and
-    the seven posterior leaves (12 words a query) shipped to the device."""
+    of flush, gather, predict, host packing and readback, and the bytes
+    really shipped: the packed float32 buffer of 11 planes over the padded
+    queries in, the mean and std planes out."""
     store = PosteriorStore()
     svc = PredictionService(_fit(("bwa", "idx")), _benches(), store=store,
                             tenant="a", workflow="w")
@@ -117,9 +118,11 @@ def test_one_frontend_dispatch(tracing):
     for name in ("lotaru.frontend.flush", "lotaru.store.gather",
                  "lotaru.compute.predict", "lotaru.compute.readback"):
         assert sp[name]["count"] == 1, name
-    assert sp["lotaru.compute.pad"]["count"] == 2      # pads, then slices
-    assert ct["lotaru.compute.h2d_bytes"] == 4 * q * 12
-    assert ct["lotaru.compute.d2h_bytes"] == 4 * q * 2
+    assert sp["lotaru.compute.pad"]["count"] == 1
+    qp = 1024                                          # one padded tile
+    assert q < qp
+    assert ct["lotaru.compute.h2d_bytes"] == 4 * 11 * qp
+    assert ct["lotaru.compute.d2h_bytes"] == 4 * 2 * qp
     flush = sp["lotaru.frontend.flush"]
     children = (sp["lotaru.store.gather"]["total_s"]
                 + sp["lotaru.compute.predict"]["total_s"])

@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import bayes_fit as bf
 from repro.kernels import decision_plane as dp
 from repro.kernels.bayes_fit import bayes_fit_ragged, bayes_predict
 
@@ -55,6 +56,22 @@ def test_bayes_predict_compiles_at_16k_queries(one_chip):
                for k in ("beta_prec", "x_mu", "x_sd", "y_mu", "y_sd")}}
     text = _compile_text(bayes_predict, _spec((q,), f32, one_chip), post)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("rows", [8, 128])
+def test_packed_predict_entry_is_one_named_kernel(one_chip, rows):
+    """The jitted predict entry compiles to the Pallas call alone, and the
+    call's instruction keeps the entry's name `_bayes_predict_jit`: a
+    device trace finds the kernel by it."""
+    from repro.kernels import ops
+    planes = _spec((bf.PREDICT_PLANES, rows, bf.LANE), jnp.float32, one_chip)
+    text = ops._bayes_predict_jit.lower(planes, "pallas").compile().as_text()
+    entry = text[text.index("ENTRY"):]
+    calls = [ln for ln in entry.splitlines() if " custom-call(" in ln]
+    assert len(calls) == 1 and "tpu_custom_call" in calls[0]
+    assert calls[0].split("=", 1)[0].split()[-1].startswith(
+        "%_bayes_predict_jit")
+    assert "fusion(" not in entry
 
 
 @pytest.mark.parametrize("tasks", [4096, 130])
