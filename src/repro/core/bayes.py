@@ -428,6 +428,11 @@ def nig_refit(nig0: dict, x: np.ndarray, y: np.ndarray) -> dict:
     return out
 
 
+# refit points are padded to a multiple of this: a full streamed ring (256)
+# plus the profiling points needs three compiled programs at most
+_REFIT_BUCKET = 128
+
+
 def refresh_fit(fit_x, fit_y, buf_x, buf_y) -> dict:
     """Periodic evidence refresh (the maintenance plane's scalar oracle):
     re-run the MacKay fixed point over the fit-time profiling points plus
@@ -439,15 +444,24 @@ def refresh_fit(fit_x, fit_y, buf_x, buf_y) -> dict:
     re-chooses both from everything observed.  Either side may be empty
     (a promoted median-fallback task has no fit-time regression data: its
     streamed-only observations are preserved and refit on their own), but
-    not both.  Returns a predict_blr/nig_from_blr-compatible posterior."""
+    not both.  Returns a predict_blr/nig_from_blr-compatible posterior.
+
+    The points are padded (masked) to a multiple of `_REFIT_BUCKET` and
+    fitted by the jitted `fit_blr_batch`: an eager `fit_blr` re-traces
+    and compiles its fixed-point loop on every call, and a promoted task's
+    buffer grows one observation at a time."""
     x = np.concatenate([np.asarray(fit_x, np.float64).ravel(),
                         np.asarray(buf_x, np.float64).ravel()])
     y = np.concatenate([np.asarray(fit_y, np.float64).ravel(),
                         np.asarray(buf_y, np.float64).ravel()])
     if x.size == 0:
         raise ValueError("refresh_fit needs at least one observation")
-    return {k: np.asarray(v) for k, v in
-            fit_blr(x.astype(np.float32), y.astype(np.float32)).items()}
+    n = x.size
+    width = -(-n // _REFIT_BUCKET) * _REFIT_BUCKET
+    xp, yp, m = (np.zeros((1, width), np.float32) for _ in range(3))
+    xp[0, :n], yp[0, :n], m[0, :n] = x, y, 1.0
+    return {k: np.asarray(v)[0] for k, v in
+            fit_blr_batch(xp, yp, m).items()}
 
 
 def nig_to_blr(nig: dict) -> dict:

@@ -18,6 +18,13 @@ a dirty-subset predict — not a full gather — plus the fused HEFT engine
 `heft.heft_schedule_matrix`; small frontiers take the NumPy sweep, large
 ones one jitted dispatch).  The drift bands and the speculation policy
 read the same resident matrix.
+
+With `repro.obs` on, a completion is the span `lotaru.plan.completion`,
+holding `lotaru.plan.observe` (the posterior update), `lotaru.plan.drift`
+(the frontier's batched predict and the band check) and, on drift,
+`lotaru.plan.replan`; a run's first plan is `lotaru.plan.initial`.
+Counters: `lotaru.plan.completions`, `lotaru.plan.replans` and
+`lotaru.plan.frontier_cells` (replanned tasks x nodes).
 """
 from __future__ import annotations
 
@@ -26,6 +33,7 @@ from typing import Dict, List, Mapping, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core.extrapolation import MachineBench
 from repro.core.microbench import NodeSpec
 from repro.online.events import PredictionQuery, TaskCompletion
@@ -86,6 +94,10 @@ class OnlineReschedulingPlanner:
         # subsets of the resident one (elementwise per row -> bitwise
         # equal to a fresh per-frontier gather)
         self._plane = FusedPlane(self.service, nodes, dag=dag)
+        # every frontier sub-DAG packs its deps to the whole DAG's fan-in,
+        # so a run's jitted sweeps differ in the task bucket alone
+        self._dep_width = max((len(t.deps) for t in dag.tasks.values()),
+                              default=0)
         self.stats = RescheduleStats()
         self._since_resched = 10 ** 9
         # uid -> (ref mean, ref std) on its currently-assigned node
@@ -94,6 +106,12 @@ class OnlineReschedulingPlanner:
         # last-planned matrix rows per uid (means/stds over all nodes) —
         # what the speculation policy reads for running tasks
         self._dist_rows: Dict[str, TaskDistribution] = {}
+
+    @property
+    def plane(self) -> FusedPlane:
+        """The resident decision plane every planning pass is served
+        from."""
+        return self._plane
 
     # ---- batched prediction matrix ------------------------------------------
     def _prediction_matrix(self, uids) -> PredictionMatrix:
@@ -124,11 +142,13 @@ class OnlineReschedulingPlanner:
 
     # ---- executor protocol --------------------------------------------------
     def initial_schedule(self) -> Schedule:
-        mat = self._prediction_matrix(self.dag.tasks)
-        sched = fused_heft_schedule(self.dag, self.nodes, mat,
-                                    quantile=self.quantile,
-                                    rank_cache=self._plane.rank_cache,
-                                    engine=self.engine)
+        with obs.span("lotaru.plan.initial"):
+            mat = self._prediction_matrix(self.dag.tasks)
+            sched = fused_heft_schedule(self.dag, self.nodes, mat,
+                                        quantile=self.quantile,
+                                        rank_cache=self._plane.rank_cache,
+                                        engine=self.engine,
+                                        dep_width=self._dep_width)
         self._band.clear()
         self._snapshot_bands(mat, sched.assignment)
         self._since_resched = 10 ** 9
@@ -136,41 +156,53 @@ class OnlineReschedulingPlanner:
 
     def on_completion(self, rec: ExecRecord, state: SimState
                       ) -> Optional[Schedule]:
-        t = self.dag.tasks[rec.uid]
-        self.stats.completions += 1
-        self._since_resched += 1
-        if rec.attempt == 0:
-            # failure re-runs (attempt > 0) span recovery downtime — their
-            # wall time is not the task's runtime, so they never reach the
-            # posterior
-            self.online.observe(TaskCompletion(
-                workflow=t.workflow, uid=rec.uid, task=t.task_name,
-                node=rec.node, input_gb=t.input_gb,
-                runtime_s=rec.finish - rec.start, finish_time=rec.finish))
+        with obs.span("lotaru.plan.completion"):
+            obs.count("lotaru.plan.completions", 1)
+            t = self.dag.tasks[rec.uid]
+            self.stats.completions += 1
+            self._since_resched += 1
+            if rec.attempt == 0:
+                # failure re-runs (attempt > 0) span recovery downtime —
+                # their wall time is not the task's runtime, so they never
+                # reach the posterior
+                with obs.span("lotaru.plan.observe"):
+                    self.online.observe(TaskCompletion(
+                        workflow=t.workflow, uid=rec.uid, task=t.task_name,
+                        node=rec.node, input_gb=t.input_gb,
+                        runtime_s=rec.finish - rec.start,
+                        finish_time=rec.finish))
 
-        frontier = [u for u in self.dag.tasks if u not in state.started]
-        if not frontier:
-            return None
-        # one batched sweep over the frontier on its assigned nodes
+            frontier = [u for u in self.dag.tasks if u not in state.started]
+            if not frontier:
+                return None
+            with obs.span("lotaru.plan.drift"):
+                drifted = self._drifted(frontier)
+            if not drifted:
+                return None
+            self.stats.drift_events += 1
+            if self._since_resched <= self.cooldown:
+                return None
+            self._since_resched = 0
+            self.stats.reschedules += 1
+            obs.count("lotaru.plan.replans", 1)
+            obs.count("lotaru.plan.frontier_cells",
+                      len(frontier) * len(self.nodes))
+            with obs.span("lotaru.plan.replan"):
+                return self._replan(state, set(frontier))
+
+    def _drifted(self, frontier: List[str]) -> bool:
+        """One batched sweep over the frontier on its assigned nodes: has
+        any task's mean left the band snapshotted at the last plan?"""
         queries = [PredictionQuery(self.dag.tasks[u].task_name,
                                    self._assignment[u],
                                    self.dag.tasks[u].input_gb)
                    for u in frontier]
         preds = self.service.predict_batch(queries)
-        drifted = False
         for u, (mean, _, _) in zip(frontier, preds):
             ref_mean, ref_std = self._band[u]
             if abs(mean - ref_mean) > self.z * max(ref_std, 1e-9):
-                drifted = True
-                break
-        if not drifted:
-            return None
-        self.stats.drift_events += 1
-        if self._since_resched <= self.cooldown:
-            return None
-        self._since_resched = 0
-        self.stats.reschedules += 1
-        return self._replan(state, set(frontier))
+                return True
+        return False
 
     # ---- speculation policy -------------------------------------------------
     def decide_speculation(self, uid: str, node: str, elapsed_s: float,
@@ -234,6 +266,7 @@ class OnlineReschedulingPlanner:
                                         ready_at=ready_at,
                                         node_available=node_avail,
                                         rank_cache=self._plane.rank_cache,
-                                        engine=self.engine)
+                                        engine=self.engine,
+                                        dep_width=self._dep_width)
         self._snapshot_bands(mat, new_sched.assignment, frontier)
         return new_sched

@@ -53,6 +53,14 @@ and the FIRST i with `cand + dur <= begin[i]` is exactly the slot
 IEEE floats and every arithmetic term (`cand + dur`, `est + dur`, comm
 charges) uses the same expressions as the reference, so schedules match
 bitwise, not just approximately.
+
+With `repro.obs` on: `lotaru.plane.sync` (dirty-row gather, predict and
+scatter), `lotaru.plane.cost` (the scaled view and the quantile cost
+matrix), `lotaru.sched.rank` (per-(dag, cluster) context and upward
+ranks), `lotaru.sched.ready` (external ready times as a (T, N) array),
+`lotaru.sched.sweep` (id `engine`) and `lotaru.sched.build` (the
+`Schedule` from the sweep's arrays); counters `lotaru.plane.rows_refreshed`
+and `lotaru.plane.predict_dispatches`, as in `PlaneStats`.
 """
 from __future__ import annotations
 
@@ -61,6 +69,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core.microbench import NodeSpec
 from repro.sched.heft import Schedule, comm_structure
 from repro.sched.plane import PredictionMatrix, quantile_z
@@ -83,7 +92,9 @@ _NEG_INF = float("-inf")
 _JIT_MIN_CELLS = 5000
 # task/dep dims are padded to bucket multiples so shrinking replan
 # frontiers (the rescheduler re-plans ever-smaller sub-DAGs) reuse one
-# compiled sweep instead of re-jitting per shape
+# compiled sweep instead of re-jitting per shape (a caller that plans
+# sub-DAGs of one DAG passes that DAG's fan-in as `dep_width`, so every
+# frontier shares one dep width)
 _TASK_BUCKET = 64
 _DEP_BUCKET = 4
 
@@ -94,12 +105,14 @@ class _PlanContext:
     lists, the W-independent avg-comm rank terms, and the sweep engine's
     static arrays (dep rows, output bits, a shared zero ready matrix).
     All of it is derived data — cached values are bitwise what a cold
-    round recomputes, so warm and cold rounds schedule identically."""
+    round recomputes, so warm and cold rounds schedule identically.
+    `dep_width` pads the dependency rows to at least that many columns."""
 
     __slots__ = ("dag", "order", "row_of", "names", "same", "gbps_min",
                  "succ", "avg_comm", "dep_rows", "gb8", "zeros", "slot_cap")
 
-    def __init__(self, dag: WorkflowDAG, nodes: List[NodeSpec]):
+    def __init__(self, dag: WorkflowDAG, nodes: List[NodeSpec],
+                 dep_width: int = 0):
         self.dag = dag      # strong ref: the cache key includes id(dag),
         # which stays unique only while the dag is alive
         self.order = dag.topo_order()
@@ -116,7 +129,7 @@ class _PlanContext:
                                 / (n_nodes ** 2))
         n_tasks = len(self.order)
         depth = max((len(dag.tasks[u].deps) for u in self.order), default=0)
-        depth = max(-(-max(depth, 1) // _DEP_BUCKET) * _DEP_BUCKET, 1)
+        depth = -(-max(depth, dep_width, 1) // _DEP_BUCKET) * _DEP_BUCKET
         self.dep_rows = np.full((n_tasks, depth), -1, np.int32)
         for i, u in enumerate(self.order):
             for k, d in enumerate(dag.tasks[u].deps):
@@ -146,13 +159,13 @@ _CTX_CACHE_MAX = 32
 
 
 def _context(dag: WorkflowDAG, nodes: List[NodeSpec],
-             rank_cache: Optional[dict]) -> _PlanContext:
+             rank_cache: Optional[dict], dep_width: int = 0) -> _PlanContext:
     if rank_cache is None:
-        return _PlanContext(dag, nodes)
-    key = (id(dag), len(dag.tasks), tuple(n.name for n in nodes))
+        return _PlanContext(dag, nodes, dep_width)
+    key = (id(dag), len(dag.tasks), tuple(n.name for n in nodes), dep_width)
     ctx = rank_cache.get(key)
     if ctx is None or ctx.dag is not dag:
-        ctx = rank_cache[key] = _PlanContext(dag, nodes)
+        ctx = rank_cache[key] = _PlanContext(dag, nodes, dep_width)
         while len(rank_cache) > _CTX_CACHE_MAX:    # bound replan-frontier
             rank_cache.pop(next(iter(rank_cache)))  # churn (FIFO evict)
     return ctx
@@ -260,7 +273,8 @@ def fused_heft_schedule(dag: WorkflowDAG, nodes: List[NodeSpec],
                         quantile: Optional[float] = None,
                         rank_cache: Optional[dict] = None,
                         engine: str = "auto",
-                        W: Optional[np.ndarray] = None) -> Schedule:
+                        W: Optional[np.ndarray] = None,
+                        dep_width: int = 0) -> Schedule:
     """Fused-engine HEFT: bit-identical to `heft.heft_schedule_matrix`.
 
     `ready_at` additionally accepts a precomputed (T, N) array (rows in
@@ -273,18 +287,28 @@ def fused_heft_schedule(dag: WorkflowDAG, nodes: List[NodeSpec],
     (`kernels.decision_plane.eft_sweep` in float64); 'auto' picks by
     problem size.  `W` overrides the cost matrix (topo-row order) — the
     resident plane passes its fused cost view so the matrix is never
-    re-derived here."""
-    ctx = _context(dag, nodes, rank_cache)
+    re-derived here.  `dep_width`: the jit sweep's dependency columns are
+    at least this many (rounded up to a bucket); a caller that plans
+    sub-DAGs of one DAG passes that DAG's largest fan-in, so all of them
+    hit one compiled sweep per task bucket."""
+    with obs.span("lotaru.sched.rank"):
+        ctx = _context(dag, nodes, rank_cache, dep_width)
     if W is None:
-        W = matrix.costs(ctx.order, ctx.names, quantile=quantile)  # (T, N)
-    rank = ctx.ranks(dag, W)
+        with obs.span("lotaru.plane.cost"):
+            W = matrix.costs(ctx.order, ctx.names, quantile=quantile)
+    with obs.span("lotaru.sched.rank"):
+        rank = ctx.ranks(dag, W)
+    if ready_at is not None and not isinstance(ready_at, np.ndarray):
+        with obs.span("lotaru.sched.ready"):
+            ready_at = _ready_rows(ctx, dag, nodes, ready_at)
     if engine == "auto":
         engine = "jit" if W.size >= _JIT_MIN_CELLS else "numpy"
-    if engine == "jit":
-        return _schedule_jit(ctx, dag, nodes, W, rank, ready_at,
-                             node_available)
-    return _schedule_numpy(ctx, dag, nodes, W, rank, ready_at,
-                           node_available)
+    with obs.span("lotaru.sched.sweep", engine=engine):
+        if engine == "jit":
+            return _schedule_jit(ctx, dag, nodes, W, rank, ready_at,
+                                 node_available)
+        return _schedule_numpy(ctx, dag, nodes, W, rank, ready_at,
+                               node_available)
 
 
 def _schedule_numpy(ctx: _PlanContext, dag: WorkflowDAG,
@@ -327,8 +351,9 @@ def _schedule_numpy(ctx: _PlanContext, dag: WorkflowDAG,
         sched.est[u] = (est_j, eft_j)
         finish[u] = eft_j
         assign_idx[u] = j
-    for name in sched.order:
-        sched.order[name].sort(key=lambda u: sched.est[u][0])
+    with obs.span("lotaru.sched.build"):
+        for name in sched.order:
+            sched.order[name].sort(key=lambda u: sched.est[u][0])
     return sched
 
 
@@ -376,21 +401,22 @@ def _build_schedule(ctx: _PlanContext, order_arr: np.ndarray,
     """Rehydrate a `Schedule` from the sweep's flat outputs, visiting
     tasks in rank order (the order the reference appends in) so per-node
     lists tie-break identically before the final est sort."""
-    n_tasks = len(ctx.order)
-    sched = Schedule(order={name: [] for name in ctx.names})
-    order, names = ctx.order, ctx.names
-    for t in range(len(order_arr)):
-        i = int(order_arr[t])
-        if i < 0 or i >= n_tasks:
-            continue
-        u = order[i]
-        name = names[int(assign[i])]
-        sched.assignment[u] = name
-        sched.order[name].append(u)
-        sched.est[u] = (float(est[i]), float(eft[i]))
-    for name in sched.order:
-        sched.order[name].sort(key=lambda u: sched.est[u][0])
-    return sched
+    with obs.span("lotaru.sched.build"):
+        n_tasks = len(ctx.order)
+        sched = Schedule(order={name: [] for name in ctx.names})
+        order, names = ctx.order, ctx.names
+        for t in range(len(order_arr)):
+            i = int(order_arr[t])
+            if i < 0 or i >= n_tasks:
+                continue
+            u = order[i]
+            name = names[int(assign[i])]
+            sched.assignment[u] = name
+            sched.order[name].append(u)
+            sched.est[u] = (float(est[i]), float(eft[i]))
+        for name in sched.order:
+            sched.order[name].sort(key=lambda u: sched.est[u][0])
+        return sched
 
 
 def sweep_device():
@@ -451,9 +477,10 @@ class FusedPlane:
     rows whose store blocks moved since the last round (generation-tagged
     dirty detection) and `matrix()` serves the scaled `PredictionMatrix`
     view — elementwise-identical to `PredictionService.predict_matrix`,
-    asserted by the parity suite.  On TPU the row stack lives as device
-    arrays and the in-place row updates are device scatters
-    (`kernels.decision_plane`); on CPU it is float64 NumPy either way.
+    asserted by the parity suite.  On every platform the row stack is
+    float64 NumPy on the host and `apply_rows` scatters re-predicted rows
+    into it there; only the dirty rows' predictive runs on the device
+    (`store.compute.predict_stacked`), one dispatch per `sync()`.
     """
 
     def __init__(self, service, nodes: Sequence[NodeSpec],
@@ -527,22 +554,25 @@ class FusedPlane:
             self._mean_raw[idx] = mean
             self._std_raw[idx] = std
             self.stats.rows_refreshed += len(idx)
+            obs.count("lotaru.plane.rows_refreshed", len(idx))
         self._generation = snap.generation
 
     def sync(self) -> int:
         """One round's resident-row maintenance: dirty-row gather +
         predict + in-place scatter.  Returns the number of rows
         refreshed."""
-        snap, idx = self.collect_dirty()
-        if len(idx):
-            post = snap.gather([self._keys[i] for i in idx])
-            mean, std = compute.predict_stacked(self._x[idx], post,
-                                                impl=self.impl)
-            self.stats.predict_dispatches += 1
-            self.apply_rows(snap, idx, mean, std)
-        else:
-            self.apply_rows(snap, idx, np.empty(0), np.empty(0))
-        return len(idx)
+        with obs.span("lotaru.plane.sync"):
+            snap, idx = self.collect_dirty()
+            if len(idx):
+                post = snap.gather([self._keys[i] for i in idx])
+                mean, std = compute.predict_stacked(self._x[idx], post,
+                                                    impl=self.impl)
+                self.stats.predict_dispatches += 1
+                obs.count("lotaru.plane.predict_dispatches", 1)
+                self.apply_rows(snap, idx, mean, std)
+            else:
+                self.apply_rows(snap, idx, np.empty(0), np.empty(0))
+            return len(idx)
 
     # ---- scaled matrix view ------------------------------------------------
     def matrix(self) -> PredictionMatrix:
@@ -553,24 +583,25 @@ class FusedPlane:
         Cached until rows, factors, or corrections move."""
         self.stats.rounds += 1
         self.sync()
-        binding = self.binding
-        if self._base_f is None \
-                or binding.factor_version != self._base_f_version:
-            self._base_f = binding.base_factor_matrix(self._tasks,
-                                                      self.node_names)
-            self._base_f_version = binding.factor_version
-        corr_map = binding.node_corrections(self.node_names)
-        corr = tuple(corr_map.get(n, 1.0) for n in self.node_names)
-        key = (self._generation, self._base_f_version, corr)
-        if self._matrix is None or key != self._matrix_key:
-            f = self._base_f * np.asarray(corr, np.float64)[None, :]
-            mean, std = compute.scale(self._mean_raw[:, None],
-                                      self._std_raw[:, None], f)
-            self._matrix = PredictionMatrix(self.uids, self.node_names,
-                                            mean, std)
-            self._matrix_key = key
-            self.stats.matrix_rebuilds += 1
-        return self._matrix
+        with obs.span("lotaru.plane.cost"):
+            binding = self.binding
+            if self._base_f is None \
+                    or binding.factor_version != self._base_f_version:
+                self._base_f = binding.base_factor_matrix(self._tasks,
+                                                          self.node_names)
+                self._base_f_version = binding.factor_version
+            corr_map = binding.node_corrections(self.node_names)
+            corr = tuple(corr_map.get(n, 1.0) for n in self.node_names)
+            key = (self._generation, self._base_f_version, corr)
+            if self._matrix is None or key != self._matrix_key:
+                f = self._base_f * np.asarray(corr, np.float64)[None, :]
+                mean, std = compute.scale(self._mean_raw[:, None],
+                                          self._std_raw[:, None], f)
+                self._matrix = PredictionMatrix(self.uids, self.node_names,
+                                                mean, std)
+                self._matrix_key = key
+                self.stats.matrix_rebuilds += 1
+            return self._matrix
 
     # ---- resident cost view ------------------------------------------------
     def cost_view(self, dag: WorkflowDAG, quantile: Optional[float]
@@ -582,27 +613,28 @@ class FusedPlane:
         replan re-derives nothing — same expressions as
         `PredictionMatrix.costs`, hence bitwise-equal schedules."""
         mat = self.matrix()
-        ctx = _context(dag, self.nodes, self.rank_cache)
-        # the ctx object in the key pins the dag: id-recycling after a
-        # frontier dag dies can never alias a stale view
-        vkey = (self._matrix_key, ctx)
-        if self._view is None or self._view_key != vkey:
-            rows = np.asarray([mat.uid_index[u] for u in ctx.order],
-                              np.int64)
-            cols = np.asarray([mat.node_index[n] for n in ctx.names],
-                              np.int64)
-            self._view = (mat.means[np.ix_(rows, cols)],
-                          mat.stds[np.ix_(rows, cols)])
-            self._view_key = vkey
-            self._cost_cache.clear()
-        W = self._cost_cache.get(quantile)
-        if W is None:
-            mean_g, std_g = self._view
-            z = None if quantile is None else quantile_z(quantile)
-            W = compute.cost_matrix(mean_g, std_g, z)
-            self._cost_cache[quantile] = W
-            self.stats.cost_rebuilds += 1
-        return mat, W
+        with obs.span("lotaru.plane.cost"):
+            ctx = _context(dag, self.nodes, self.rank_cache)
+            # the ctx object in the key pins the dag: id-recycling after a
+            # frontier dag dies can never alias a stale view
+            vkey = (self._matrix_key, ctx)
+            if self._view is None or self._view_key != vkey:
+                rows = np.asarray([mat.uid_index[u] for u in ctx.order],
+                                  np.int64)
+                cols = np.asarray([mat.node_index[n] for n in ctx.names],
+                                  np.int64)
+                self._view = (mat.means[np.ix_(rows, cols)],
+                              mat.stds[np.ix_(rows, cols)])
+                self._view_key = vkey
+                self._cost_cache.clear()
+            W = self._cost_cache.get(quantile)
+            if W is None:
+                mean_g, std_g = self._view
+                z = None if quantile is None else quantile_z(quantile)
+                W = compute.cost_matrix(mean_g, std_g, z)
+                self._cost_cache[quantile] = W
+                self.stats.cost_rebuilds += 1
+            return mat, W
 
     # ---- scheduling --------------------------------------------------------
     def schedule(self, dag: WorkflowDAG, ready_at=None,
@@ -674,6 +706,7 @@ def replan_many(requests: Sequence[ReplanRequest],
                                      mean_all[off:off + len(idx)],
                                      std_all[off:off + len(idx)])
                 req.plane.stats.predict_dispatches += 1
+                obs.count("lotaru.plane.predict_dispatches", 1)
                 off += len(idx)
             else:
                 req.plane.apply_rows(snap, idx, np.empty(0), np.empty(0))

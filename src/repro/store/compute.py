@@ -36,6 +36,7 @@ def predict_stacked(x: np.ndarray, post: dict, impl: str = "auto"
     from repro.core import bayes
     from repro.kernels import ops
     with obs.span("lotaru.compute.predict"):
+        obs.count("lotaru.compute.queries", len(x))
         if not (impl in ("pallas", "interpret")
                 or (impl == "auto" and ops._on_tpu())):
             return bayes.predict_blr_np(post, np.asarray(x, np.float64))

@@ -126,6 +126,60 @@ def test_auto_engine_policy_is_size_based(monkeypatch):
     assert calls
 
 
+def _frontier(dag, started, name):
+    """The unstarted sub-DAG: what `OnlineReschedulingPlanner._replan`
+    plans after the tasks in `started` were booked."""
+    sub = WorkflowDAG(name)
+    for u in dag.topo_order():
+        if u in started:
+            continue
+        t = dag.tasks[u]
+        sub.add(TaskInstance(u, t.task_name, t.workflow, t.input_gb,
+                             t.output_gb, deps=[d for d in t.deps
+                                                if d not in started]))
+    return sub
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_frontiers_of_a_run_share_one_dep_width(seed):
+    """A replan's frontier is the complement of a set closed under
+    dependencies, so its fan-in is at most the whole DAG's.  Passed that
+    fan-in as `dep_width`, every frontier packs its dependencies to one
+    width (its jitted sweeps differ in the task bucket alone) and the
+    schedule is still bitwise HEFT; a DAG planned on its own keeps the
+    narrowest bucket."""
+    rng = np.random.default_rng(seed)
+    dag, nodes, svc = _build(40, 4, seed)
+    mat = _matrix(dag, nodes, svc)
+    fan_in = max(len(t.deps) for t in dag.tasks.values())
+    bucket = -(-fan_in // fused_mod._DEP_BUCKET) * fused_mod._DEP_BUCKET
+    assert fused_mod._PlanContext(dag, nodes).dep_rows.shape[1] == bucket
+    widths, own = set(), set()
+    for k in range(12):
+        started, p = set(), rng.uniform(0.1, 0.9)
+        for u in dag.topo_order():
+            if all(d in started for d in dag.tasks[u].deps) \
+                    and rng.random() < p:
+                started.add(u)
+        sub = _frontier(dag, started, dag.name)
+        if not sub.tasks:
+            continue
+        widths.add(fused_mod._PlanContext(sub, nodes, fan_in)
+                   .dep_rows.shape[1])
+        own.add(fused_mod._PlanContext(sub, nodes).dep_rows.shape[1])
+        if k < 3:
+            sub_mat = PredictionMatrix(
+                tuple(sub.tasks), mat.node_names,
+                mat.means[[mat.uid_index[u] for u in sub.tasks]],
+                mat.stds[[mat.uid_index[u] for u in sub.tasks]])
+            want = heft_schedule_matrix(sub, nodes, sub_mat, quantile=0.95)
+            got = fused_heft_schedule(sub, nodes, sub_mat, quantile=0.95,
+                                      engine="jit", dep_width=fan_in + 5)
+            _same_schedule(got, want)
+    assert widths == {bucket}
+    assert max(own) <= bucket
+
+
 def test_upward_rank_kernel_matches_host_recurrence():
     import jax
 

@@ -1,6 +1,7 @@
 """The in-program tracer: off by default, self times of nested spans,
 threads recording at once, and the spans and counters of one front-end
-dispatch and one fleet refresh pass."""
+dispatch, one fleet refresh pass and one workflow run replanned on
+drift."""
 import sys
 import threading
 import time
@@ -9,10 +10,14 @@ import numpy as np
 import pytest
 
 from repro import obs
-from repro.online import (FleetRefresher, OnlinePredictor, PredictionService,
+from repro.online import (FleetRefresher, OnlinePredictor,
+                          OnlineReschedulingPlanner, PredictionService,
                           RefreshPolicy)
+from repro.sched.cluster import TARGET_MACHINES
 from repro.store import AsyncPredictionFrontend, PosteriorStore
+from repro.workflow.simulator import execute_adaptive
 
+from test_online import _experiment
 from test_refresh import _observe_local
 from test_store import _benches, _fit, _queries
 
@@ -157,3 +162,68 @@ def test_one_refresh_pass(tracing, rng):
     assert 0 < ct["lotaru.refresh.fit_points"] <= ct[
         "lotaru.refresh.fit_cells"]
     assert ct["lotaru.refresh.fit_cells"] % 4 == 0     # 4 rows x N columns
+
+
+def _replanned_run():
+    """eager on the five Table 2 targets with C2 four times slower than
+    benchmarked: the planner replans on drift."""
+    gt, dag, lot, benches = _experiment("eager")
+    nodes = list(TARGET_MACHINES)
+    true_rt = lambda u, n: gt.runtime(dag.tasks[u].task_name,
+                                      dag.tasks[u].input_gb, n, u) \
+        * {"C2": 4.0}.get(n.name, 1.0)
+    planner = OnlineReschedulingPlanner(
+        dag, nodes, OnlinePredictor(lot, benches=benches), benches=benches,
+        quantile=0.95)
+    execute_adaptive(dag, nodes, planner, true_rt)
+    return planner, dag, nodes
+
+
+def test_replanned_run_spans_agree_with_planner_stats(tracing):
+    planner, dag, nodes = _replanned_run()
+    st, ps = planner.stats, planner.plane.stats
+    assert st.reschedules >= 1
+    passes = 1 + st.reschedules                  # the first plan, replans
+    snap = obs.snapshot()
+    sp, ct = snap["spans"], snap["counters"]
+    assert (ct["lotaru.plan.completions"] == st.completions
+            == sp["lotaru.plan.completion"]["count"]
+            == sp["lotaru.plan.observe"]["count"] == len(dag.tasks))
+    assert (ct["lotaru.plan.replans"] == sp["lotaru.plan.replan"]["count"]
+            == st.reschedules)
+    assert sp["lotaru.plan.initial"]["count"] == 1
+    assert st.drift_events <= sp["lotaru.plan.drift"]["count"] < len(
+        dag.tasks)
+    cells = ct["lotaru.plan.frontier_cells"]
+    assert cells % len(nodes) == 0
+    assert st.reschedules * len(nodes) <= cells < passes * len(
+        dag.tasks) * len(nodes)
+    assert ct["lotaru.plane.rows_refreshed"] == ps.rows_refreshed
+    assert ct["lotaru.plane.predict_dispatches"] == ps.predict_dispatches
+    assert sp["lotaru.plane.sync"]["count"] == ps.rounds == passes
+    assert sp["lotaru.plane.cost"]["count"] == 2 * passes
+    assert sp["lotaru.sched.rank"]["count"] == 2 * passes
+    assert sp["lotaru.sched.ready"]["count"] == st.reschedules
+    for name in ("lotaru.sched.sweep", "lotaru.sched.build"):
+        assert sp[name]["count"] == passes, name
+    # every predict call is a drift check, a replan's running tasks or a
+    # plane dispatch; each drift check asks at least one query
+    calls = sp["lotaru.compute.predict"]["count"]
+    assert (sp["lotaru.plan.drift"]["count"] + ps.predict_dispatches
+            <= calls <= sp["lotaru.plan.drift"]["count"]
+            + ps.predict_dispatches + st.reschedules)
+    assert ct["lotaru.compute.queries"] >= (ps.rows_refreshed
+                                            + sp["lotaru.plan.drift"]["count"])
+    done = sp["lotaru.plan.completion"]
+    inside = sum(sp[f"lotaru.plan.{k}"]["total_s"]
+                 for k in ("observe", "drift", "replan"))
+    assert done["self_s"] == pytest.approx(done["total_s"] - inside,
+                                           abs=1e-9)
+
+
+def test_replanned_run_records_nothing_with_tracer_off():
+    obs.reset()
+    assert not obs.enabled()
+    planner, _, _ = _replanned_run()
+    assert planner.stats.reschedules >= 1
+    assert obs.snapshot() == {"spans": {}, "counters": {}}

@@ -164,6 +164,28 @@ def test_refresh_preserves_streamed_only_observations(rng):
     assert got == pytest.approx(float(want), rel=2e-3)
 
 
+def test_refresh_fit_compiles_once_for_a_growing_buffer(rng):
+    """A promoted task refits a ring that grows one point at a time: every
+    length up to the padding bucket runs the one compiled fit."""
+    import jax
+    compiles = []
+
+    def listen(event, secs, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(secs)
+    x = rng.uniform(0.5, 8.0, 40)
+    y = 3.0 + 2.0 * x + rng.normal(0.0, 0.1, 40)
+    bayes.refresh_fit([], [], x[:4], y[:4])
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        posts = [bayes.refresh_fit([], [], x[:n], y[:n])
+                 for n in range(5, 40)]
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert compiles == []
+    assert all(float(p["n"]) == n for p, n in zip(posts, range(5, 40)))
+
+
 def test_refresh_out_of_band_snapshot_isolation(rng):
     """readers holding a pre-refresh snapshot keep serving it; the refresh
     lands as one atomic generation — in-flight predict batches are never
