@@ -148,27 +148,60 @@ def constant_posterior(mean: float, std: float) -> dict:
 # All state is float64 numpy: thousands of sequential Sherman-Morrison
 # updates stay exact to ~1e-12 where float32 would drift.
 
-def nig_from_blr(post: dict) -> dict:
-    """Lift a fitted BLR posterior into a streaming NIG state.
+_NIG_SCALARS = ("a", "b", "x_mu", "x_sd", "y_mu", "y_sd", "n0", "n_obs",
+                "s2_lift")
+
+
+def nig_from_blr_stacked(post: dict) -> dict:
+    """Lift fitted BLR posteriors into streaming NIG fields, over any
+    leading batch shape: (T, ...) leaves from `store.compute.fit_stacked`
+    give (T, ...) fields, unbatched leaves give unbatched ones.
 
     Moment matching: the MacKay posterior has weight covariance `sigma` and
     noise precision `beta_prec`; we take E[s2] = b/a = 1/beta_prec with
     a = max(n/2, 1) pseudo-observations of noise, and V = sigma * beta_prec
-    so that E[s2] * V equals the fitted weight covariance exactly."""
+    so that E[s2] * V equals the fitted weight covariance exactly.
+
+    Every field is elementwise float64 per task, and the stacked `inv`
+    runs the same LAPACK routine per 2x2 matrix, so a task's fields are
+    bit-identical whether it is lifted alone or in a stack."""
     sigma = np.asarray(post["sigma"], np.float64)
-    beta = float(post["beta_prec"])
-    a = max(float(post["n"]) / 2.0, 1.0)
-    v = sigma * beta
-    return {"mu": np.asarray(post["mu"], np.float64).copy(),
-            "v": v, "prec": np.linalg.inv(v),
-            "a": a, "b": a / beta,
-            "x_mu": float(post["x_mu"]), "x_sd": float(post["x_sd"]),
-            "y_mu": float(post["y_mu"]), "y_sd": float(post["y_sd"]),
-            "n0": float(post["n"]), "n_obs": 0.0,
+    beta = np.asarray(post["beta_prec"], np.float64)
+    n0 = np.asarray(post["n"], np.float64)
+    a = np.maximum(n0 / 2.0, 1.0)
+    v = sigma * beta[..., None, None]
+    return {"mu": np.array(post["mu"], np.float64), "v": v,
+            "prec": np.linalg.inv(v), "a": a, "b": a / beta,
+            "x_mu": np.asarray(post["x_mu"], np.float64),
+            "x_sd": np.asarray(post["x_sd"], np.float64),
+            "y_mu": np.asarray(post["y_mu"], np.float64),
+            "y_sd": np.asarray(post["y_sd"], np.float64),
+            "n0": n0, "n_obs": np.zeros(np.shape(n0)),
             # noise level the evidence fixed point chose at lift time; the
             # maintenance plane's drift trigger compares the streaming
             # estimate b/a against it (see online.maintenance.RefreshPolicy)
             "s2_lift": 1.0 / beta}
+
+
+def nig_rows(nig: dict) -> list:
+    """Split (T, ...) NIG fields into T streaming states: float64 arrays
+    for mu, v and prec (views of row t: nothing updates a state in place,
+    `nig_update` and the folds build new arrays) and Python floats for
+    the scalars, the types `nig_from_blr` gives and checkpoints hold."""
+    return [{"mu": mu, "v": v, "prec": prec, "a": a, "b": b, "x_mu": x_mu,
+             "x_sd": x_sd, "y_mu": y_mu, "y_sd": y_sd, "n0": n0,
+             "n_obs": n_obs, "s2_lift": s2_lift}
+            for (mu, v, prec, a, b, x_mu, x_sd, y_mu, y_sd, n0, n_obs,
+                 s2_lift) in zip(nig["mu"], nig["v"], nig["prec"],
+                                 *(nig[k].tolist() for k in _NIG_SCALARS))]
+
+
+def nig_from_blr(post: dict) -> dict:
+    """Lift one fitted BLR posterior into a streaming NIG state: the
+    unbatched case of `nig_from_blr_stacked`."""
+    nig = nig_from_blr_stacked(post)
+    return {k: v if k in ("mu", "v", "prec") else float(v)
+            for k, v in nig.items()}
 
 
 def nig_update(nig: dict, x_new: float, y_new: float) -> dict:
@@ -464,17 +497,45 @@ def refresh_fit(fit_x, fit_y, buf_x, buf_y) -> dict:
             fit_blr_batch(xp, yp, m).items()}
 
 
-def nig_to_blr(nig: dict) -> dict:
-    """Export a streaming state back to the predict_blr posterior format.
+# the NIG fields an export reads
+NIG_EXPORT_FIELDS = ("mu", "v", "a", "b", "x_mu", "x_sd", "y_mu", "y_sd",
+                     "n0", "n_obs")
+
+
+def nig_stack(nigs) -> dict:
+    """Stack T streaming states into the (T, ...) NIG fields
+    `nig_to_blr_stacked` reads."""
+    return {k: np.array([n[k] for n in nigs], np.float64)
+            for k in NIG_EXPORT_FIELDS}
+
+
+def nig_to_blr_stacked(nig: dict) -> dict:
+    """Export streaming states back to the predict_blr posterior format
+    (float32 leaves), over any leading batch shape.
 
     The Student-t predictive scale^2 = (b/a) (1 + phi V phi) maps onto the
     Gaussian form 1/beta_prec + phi sigma phi with beta_prec = a/b and
-    sigma = (b/a) V, so downstream (batched) predict code is unchanged."""
-    s2 = nig["b"] / nig["a"]
-    return {"mu": nig["mu"].astype(np.float32),
-            "sigma": (s2 * nig["v"]).astype(np.float32),
-            "alpha": np.float32(1.0),
-            "beta_prec": np.float32(1.0 / s2),
-            "x_mu": np.float32(nig["x_mu"]), "x_sd": np.float32(nig["x_sd"]),
-            "y_mu": np.float32(nig["y_mu"]), "y_sd": np.float32(nig["y_sd"]),
-            "n": np.float32(nig["n0"] + nig["n_obs"])}
+    sigma = (b/a) V, so downstream (batched) predict code is unchanged.
+    Each leaf is computed in float64 per task and rounded once to float32,
+    so a stacked export equals the unbatched one bit for bit."""
+    s2 = (np.asarray(nig["b"], np.float64)
+          / np.asarray(nig["a"], np.float64))
+    f32 = np.float32
+    return {"mu": np.asarray(nig["mu"], np.float64).astype(f32),
+            "sigma": (s2[..., None, None] * np.asarray(nig["v"], np.float64)
+                      ).astype(f32),
+            "alpha": np.ones(np.shape(s2), f32),
+            "beta_prec": (1.0 / s2).astype(f32),
+            "x_mu": np.asarray(nig["x_mu"], np.float64).astype(f32),
+            "x_sd": np.asarray(nig["x_sd"], np.float64).astype(f32),
+            "y_mu": np.asarray(nig["y_mu"], np.float64).astype(f32),
+            "y_sd": np.asarray(nig["y_sd"], np.float64).astype(f32),
+            "n": (np.asarray(nig["n0"], np.float64)
+                  + np.asarray(nig["n_obs"], np.float64)).astype(f32)}
+
+
+def nig_to_blr(nig: dict) -> dict:
+    """Export one streaming state: the unbatched case of
+    `nig_to_blr_stacked` (float32 arrays for mu and sigma, float32
+    scalars for the rest)."""
+    return {k: v[()] for k, v in nig_to_blr_stacked(nig).items()}

@@ -19,9 +19,10 @@ This module closes that loop across the whole store:
     evidence fixed point for all of them in ONE padded/masked batched fit
     dispatch (`store.compute.fit_stacked`: Pallas kernel on TPU, jit'd vmap
     elsewhere), moment-matches the refreshed posteriors back into the
-    streaming NIG states (`OnlinePredictor.apply_refresh`), and publishes
-    every rewritten row through the store in a single copy-on-write
-    generation bump.
+    streaming NIG states in one stacked lift (each task taken up by
+    `OnlinePredictor.apply_refresh`), and publishes every rewritten row,
+    exported from the predictors' states in one stacked export, through
+    the store in a single copy-on-write generation bump (`put_stacked`).
 
 The refresh is out-of-band by construction: the expensive fit runs with no
 locks held (a fit that races a concurrent observe() is rejected per task by
@@ -41,6 +42,7 @@ from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
 from repro import obs
+from repro.store.compute import LEAVES
 from repro.store.posterior import PosteriorStore, TenantBinding
 
 
@@ -209,52 +211,65 @@ class FleetRefresher:
     def _apply(self, rows, keys, post) -> Tuple[int, int, int]:
         """Moment-match the fitted posteriors into the streaming states and
         publish them -> (rows published, tenants, stale fits)."""
-        # moment-match back into the streaming states; a task whose change
-        # seq moved while the fit ran keeps its (newer) state and stays due
+        from repro.core import bayes
+        # one stacked lift for the fleet, then a per-task take-up: a task
+        # whose change seq moved while the fit ran keeps its (newer) state
+        # and stays due
+        lifted = bayes.nig_rows(bayes.nig_from_blr_stacked(post))
         applied: List[dict] = []
-        n_stale = 0
-        for i, k in enumerate(keys):
+        for k, nig in zip(keys, lifted):
             r = rows[k]
-            row_post = {leaf: v[i] for leaf, v in post.items()}
-            if r["p"].apply_refresh(r["task"], row_post, seq=r["seq"]):
+            if r["p"].apply_refresh(r["task"], nig, seq=r["seq"]):
                 applied.append(r)
-                self._last_refresh[k] = time.monotonic()   # min_interval_s
-            else:                                          # rate-limit stamp
-                n_stale += 1
+                # applied-at stamp, the policy's min_interval_s rate limit
+                self._last_refresh[k] = time.monotonic()
+        n_stale = len(keys) - len(applied)
+        obs.count("lotaru.refresh.stale_rows", n_stale)
 
-        # publish: one put_many -> one COW generation across all tenants,
-        # then advance each binding's cursor past the rows just written.
+        # publish: one stacked export of the states the predictors now hold
+        # and one put_stacked -> one COW generation across all tenants, then
+        # advance each binding's cursor past the rows just written.
         # Binding locks are taken in namespace order (always before the
-        # store lock inside put_many — the same order sync() uses), so a
+        # store lock inside put_stacked — the same order sync() uses), so a
         # concurrent sync/flush serializes cleanly instead of deadlocking.
         bindings = sorted({id(b): b for r in applied for b in r["bindings"]
                            }.values(), key=lambda b: b.namespace)
         tenants = set()
-        n_rows = 0
         with contextlib.ExitStack() as stack:
             for b in bindings:
                 stack.enter_context(b._sync_lock)
-            items = []
+            held: List[dict] = []          # the states read back, in order
+            key_strs: List[str] = []
+            src: List[int] = []            # held state of each published key
             per_binding: Dict[int, Dict[str, int]] = {}
             for r in applied:
-                # seq captured BEFORE the export: if an observe lands in
-                # between, the exported row is fresher than the seq and the
-                # cursor advance below refuses — the row just stays due
-                seq = r["p"].change_seq(r["task"])
+                # seq captured BEFORE the state is read: if an observe lands
+                # in between, the exported row is fresher than the seq and
+                # the cursor advance below refuses — the row just stays due
+                p, task = r["p"], r["task"]
+                seq = p.change_seq(task)
+                nig = p.tasks[task].nig
+                if nig is None:          # replaced by a load_state(): its
+                    continue             # cursor refuses, the sync writes it
+                n_keys = len(key_strs)
                 for b in r["bindings"]:
                     if b._detached:      # evicted/displaced mid-refresh:
                         continue         # never write its rows back
-                    items.append((b.key(r["task"]),
-                                  r["p"].export_posterior(r["task"])))
-                    per_binding.setdefault(id(b), {})[r["task"]] = seq
+                    key_strs.append(b.key_str(task))
+                    src.append(len(held))
+                    per_binding.setdefault(id(b), {})[task] = seq
                     tenants.add(b.tenant)
-            if items:
-                self.store.put_many(items)
-                n_rows = len({str(k) for k, _ in items})
+                if len(key_strs) > n_keys:
+                    held.append(nig)
+            if key_strs:
+                out = bayes.nig_to_blr_stacked(bayes.nig_stack(held))
+                self.store.put_stacked(
+                    key_strs, {leaf: out[leaf][src] for leaf in LEAVES})
+            obs.count("lotaru.refresh.published_rows", len(key_strs))
             for b in bindings:
                 if not b._detached:
                     b._advance_cursor(per_binding.get(id(b), {}))
-        return n_rows, len(tenants), n_stale
+        return len(key_strs), len(tenants), n_stale
 
     def _record(self, report: RefreshReport) -> None:
         if len(self.reports) >= 4096:    # telemetry, not a log: a daemon

@@ -494,18 +494,19 @@ class OnlinePredictor:
         advanced past rows nothing has touched since."""
         return self._task_changes.get(task, 0)
 
-    def apply_refresh(self, task: str, post: Mapping, seq=None) -> bool:
-        """Moment-match a refreshed BLR posterior (the batched evidence
-        fixed point over this task's refresh_snapshot data) back into the
-        streaming NIG state.  Returns False — leaving the task due — when
-        `seq` shows an observation landed after the snapshot was taken
-        (checked and swapped under the state lock, so the verdict cannot
-        race a concurrent observe)."""
+    def apply_refresh(self, task: str, nig: dict, seq=None) -> bool:
+        """Take up a refreshed streaming state: the batched evidence fixed
+        point over this task's refresh_snapshot data, already lifted
+        (`bayes.nig_from_blr`, or a row of `bayes.nig_from_blr_stacked`).
+        Returns False — leaving the task due — when `seq` shows an
+        observation landed after the snapshot was taken (checked and
+        swapped under the state lock, so the verdict cannot race a
+        concurrent observe)."""
         with self._state_lock:
             st = self.tasks[task]
             if seq is not None and self._task_changes.get(task) != seq:
                 return False
-            st.nig = bayes.nig_from_blr(post)
+            st.nig = nig
             st.since_refresh = 0
             self._mark_changed(task)
             self.version += 1

@@ -632,37 +632,84 @@ class PosteriorStore:
             for ks, _ in staged:
                 if ks not in self._rows:
                     TaskKey.parse(ks)            # validate shape of new keys
-            fresh = set()
             touched: Dict[int, List[Tuple[int, dict]]] = {}
             for ks, leaves in staged:
-                row = self._rows.get(ks)
-                if row is None:
-                    if self._free_rows:         # recycle evicted slots first
-                        row = heapq.heappop(self._free_rows)
-                    else:
-                        row = self._next_row   # never len(_rows): restored
-                        self._next_row += 1    # manifests may have row ids
-                    self._rows[ks] = row       # beyond the key count
-                bid, slot = divmod(row, self.block_size)
-                while bid >= len(self._blocks):
-                    self._blocks.append(_new_block(self.block_size))
-                    fresh.add(len(self._blocks) - 1)
-                if self._blocks[bid] is None:   # released by evict()
-                    self._blocks[bid] = _new_block(self.block_size)
-                    fresh.add(bid)
+                bid, slot = divmod(self._row_for(ks), self.block_size)
                 touched.setdefault(bid, []).append((slot, leaves))
             for bid, writes in touched.items():
-                block = self._blocks[bid]
-                if bid not in fresh:             # copy-on-write
-                    block = {k: v.copy() for k, v in block.items()}
+                block = self._writable_block(bid)
                 for slot, leaves in writes:
                     for leaf, v in leaves.items():
                         block[leaf][slot] = v
-                self._blocks[bid] = block
-            self.generation += 1
-            for bid in touched:                  # incremental checkpoints
-                self._block_gen[bid] = self.generation   # persist only these
-            self._snap = None
+            self._publish(touched)
+
+    def put_stacked(self, keys: Sequence[str],
+                    leaves: Mapping[str, np.ndarray]) -> None:
+        """Write len(keys) rows given as stacked (len(keys), ...) leaves in
+        one generation bump: `put_many` for a fleet publish, which pays
+        one shape check per leaf and one fancy-index scatter per leaf and
+        touched block instead of a check and a write per row and leaf.
+        Keys are distinct key strings (`TenantBinding.key_str`).  Atomic
+        as `put_many`: a bad shape or key raises before any row, block or
+        generation changes."""
+        n = len(keys)
+        if not n:
+            return
+        staged = {}
+        for leaf in LEAVES:
+            v = np.asarray(leaves[leaf], np.float64)
+            if v.shape != (n,) + LEAF_SHAPES[leaf]:
+                raise ValueError(f"leaf {leaf!r} has shape {v.shape}, want "
+                                 f"{(n,) + LEAF_SHAPES[leaf]}")
+            staged[leaf] = v
+        if len(set(keys)) != n:
+            raise ValueError("put_stacked keys must be distinct")
+        with self._lock:
+            for ks in keys:
+                if ks not in self._rows:
+                    TaskKey.parse(ks)            # validate shape of new keys
+            rows = np.array([self._row_for(ks) for ks in keys], np.int64)
+            bids, slots = np.divmod(rows, self.block_size)
+            touched = np.unique(bids).tolist()
+            for bid in touched:
+                m = bids == bid
+                block = self._writable_block(bid)
+                for leaf, v in staged.items():
+                    block[leaf][slots[m]] = v[m]
+            self._publish(touched)
+
+    def _row_for(self, ks: str) -> int:
+        """The row of key `ks`, allocated if new.  Caller holds the lock."""
+        row = self._rows.get(ks)
+        if row is None:
+            if self._free_rows:                 # recycle evicted slots first
+                row = heapq.heappop(self._free_rows)
+            else:
+                row = self._next_row   # never len(_rows): restored
+                self._next_row += 1    # manifests may have row ids
+            self._rows[ks] = row       # beyond the key count
+        return row
+
+    def _writable_block(self, bid: int) -> Dict[str, np.ndarray]:
+        """Block `bid`, ready for one write's rows: made if new or released
+        by evict(), else copied (copy-on-write: blocks held by live
+        snapshots are never mutated).  Caller holds the lock."""
+        if bid >= len(self._blocks):
+            self._blocks.extend(_new_block(self.block_size)
+                                for _ in range(bid + 1 - len(self._blocks)))
+            return self._blocks[bid]
+        block = self._blocks[bid]
+        block = (_new_block(self.block_size) if block is None
+                 else {k: v.copy() for k, v in block.items()})
+        self._blocks[bid] = block
+        return block
+
+    def _publish(self, touched) -> None:
+        """One generation bump for a write that touched these blocks."""
+        self.generation += 1
+        for bid in touched:                      # incremental checkpoints
+            self._block_gen[bid] = self.generation   # persist only these
+        self._snap = None
 
     # ---- reads --------------------------------------------------------------
     def snapshot(self) -> StoreSnapshot:

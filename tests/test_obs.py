@@ -227,3 +227,40 @@ def test_replanned_run_records_nothing_with_tracer_off():
     planner, _, _ = _replanned_run()
     assert planner.stats.reschedules >= 1
     assert obs.snapshot() == {"spans": {}, "counters": {}}
+
+
+def _pass_with_one_stale_task(monkeypatch, rng):
+    """One refresh pass over two tenants' two due tasks each, one of which
+    is observed while the fit runs (refused by its seq check)."""
+    import repro.store.compute as compute
+    store = PosteriorStore()
+    onlines = []
+    for tenant in ("acme", "globex"):
+        online = OnlinePredictor(_fit(("bwa", "idx")))
+        store.bind(tenant, "w", online)
+        _observe_local(online, "bwa", 4, rng)
+        _observe_local(online, "idx", 4, rng, slope=12.0)
+        onlines.append(online)
+    real = compute.fit_stacked
+
+    def fit_and_race(x, y, m, impl="auto"):
+        _observe_local(onlines[1], "idx", 1, rng)
+        return real(x, y, m, impl)
+    monkeypatch.setattr(compute, "fit_stacked", fit_and_race)
+    return FleetRefresher(store, RefreshPolicy(every_n=4)).refresh()
+
+
+def test_refresh_counts_published_and_stale_rows(tracing, monkeypatch, rng):
+    report = _pass_with_one_stale_task(monkeypatch, rng)
+    assert (report.n_tasks, report.n_stale) == (3, 1)
+    ct = obs.snapshot()["counters"]
+    assert ct["lotaru.refresh.published_rows"] == 3
+    assert ct["lotaru.refresh.stale_rows"] == 1
+
+
+def test_refresh_counts_nothing_with_tracer_off(monkeypatch, rng):
+    obs.reset()
+    assert not obs.enabled()
+    report = _pass_with_one_stale_task(monkeypatch, rng)
+    assert (report.n_tasks, report.n_stale) == (3, 1)
+    assert obs.snapshot() == {"spans": {}, "counters": {}}

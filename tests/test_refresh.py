@@ -55,7 +55,8 @@ def test_refresh_due_every_n_completions(rng):
     # a refresh resets the counter
     snap = online.refresh_snapshot(["bwa"])["bwa"]
     post = bayes.refresh_fit([], [], snap[1], snap[2])
-    assert online.apply_refresh("bwa", post, seq=snap[0])
+    assert online.apply_refresh("bwa", bayes.nig_from_blr(post),
+                                seq=snap[0])
     assert online.refresh_due(policy) == []
 
 
@@ -82,7 +83,8 @@ def test_apply_refresh_rejects_stale_fit(rng):
     post = bayes.refresh_fit([], [], x, y)
     _observe_local(online, "bwa", 1, rng)           # race: new observation
     before = online.predict("bwa", 3.0)
-    assert not online.apply_refresh("bwa", post, seq=seq)
+    assert not online.apply_refresh("bwa", bayes.nig_from_blr(post),
+                                    seq=seq)
     assert online.predict("bwa", 3.0) == before
     assert online.refresh_due(RefreshPolicy(every_n=5)) == ["bwa"]
 
@@ -557,3 +559,127 @@ def test_save_keep_last_validation(tmp_path, rng):
     _warm_service(store, "t", ("a0",), rng)
     with pytest.raises(ValueError, match="keep_last"):
         store.save(str(tmp_path / "c"), keep_last=0)
+
+
+# --- the stacked apply phase against the per-task reference loop ---------------
+_FLEET_TASKS = tuple(f"t{i}" for i in range(6))
+
+
+@pytest.fixture(scope="module")
+def fleet_base():
+    return _fit(_FLEET_TASKS)
+
+
+def _bits(v):
+    return type(v), np.asarray(v).dtype, np.asarray(v).shape, \
+        np.asarray(v).tobytes()
+
+
+@pytest.mark.parametrize("n_due", [1, 2, 300])
+def test_stacked_apply_equals_per_task_reference(monkeypatch, rng, n_due,
+                                                 fleet_base):
+    """One pass over `n_due` due tasks of 50 tenants, tenant 0's predictor
+    bound into a second namespace too.  With two or more tasks due, the
+    second one's seq moves while the fit runs; with three or more, the
+    third one's namespace is evicted then.  Every published row and
+    every task's state is bit-identical to the per-task loop
+    (`nig_from_blr`, `nig_to_blr`, `put_many`); the stale task keeps its
+    newer state and stays due; the pass bumps one generation."""
+    import repro.store.compute as compute
+    store = PosteriorStore()
+    onlines = [OnlinePredictor(fleet_base) for _ in range(50)]
+    for j, online in enumerate(onlines):
+        store.bind(f"ten{j}", "w", online)
+    store.bind("ten0", "w2", onlines[0])              # shared predictor
+    due_rows = [(j, t) for t in _FLEET_TASKS for j in range(50)][:n_due]
+    for j, t in due_rows:
+        _observe_local(onlines[j], t, 2, rng)
+    policy = RefreshPolicy(every_n=2)
+    refresher = FleetRefresher(store, policy)
+    due = refresher.due()
+    assert len(due) == n_due + sum(j == 0 for j, _ in due_rows)   # ten0 x2
+    before = store.snapshot()
+
+    real = compute.fit_stacked
+    seen = {}
+
+    def fit_and_race(x, y, m, impl="auto"):
+        seen["post"] = real(x, y, m, impl)
+        if n_due >= 2:                                # an observe lands
+            j, t = due_rows[1]
+            _observe_local(onlines[j], t, 1, rng)
+            seen["stale"] = onlines[j].tasks[t].nig
+        if n_due >= 3:                                # a namespace goes
+            store.evict(f"ten{due_rows[2][0]}", "w")
+        seen["generation"] = store.generation
+        return seen["post"]
+    monkeypatch.setattr(compute, "fit_stacked", fit_and_race)
+    report = refresher.refresh(due)
+
+    assert store.generation == seen["generation"] + 1
+    assert report.generation == store.generation
+    assert report.n_stale == (1 if n_due >= 2 else 0)
+    ref_store = PosteriorStore()
+    ref_items, published = [], set()
+    fit_rows = list(dict.fromkeys((id(b.predictor), t) for b, t in due))
+    evicted = (due_rows[2][0], "w") if n_due >= 3 else None
+    for i, (j, t) in enumerate(due_rows):
+        state = onlines[j].tasks[t].nig
+        if i == 1:                                    # stale: kept, due
+            assert state is seen["stale"]
+            assert t in onlines[j].refresh_due(policy)
+            continue
+        r = fit_rows.index((id(onlines[j]), t))
+        want = bayes.nig_from_blr({k: v[r] for k, v in seen["post"].items()})
+        assert list(state) == list(want)
+        for k in want:
+            assert _bits(state[k]) == _bits(want[k]), (i, k)
+        row = bayes.nig_to_blr(want)
+        for ns in (("w", "w2") if j == 0 else ("w",)):
+            if evicted == (j, ns):                    # never written back
+                continue
+            key = str(TaskKey(f"ten{j}", ns, t))
+            ref_items.append((key, row))
+            published.add(key)
+    ref_store.put_many(ref_items)
+    assert report.n_tasks == len(published)
+    snap = store.snapshot()
+    for key in store.task_keys():
+        want = (ref_store.get(key) if key in published else before.get(key))
+        for leaf, v in snap.get(key).items():
+            assert v.tobytes() == want[leaf].tobytes(), (key, leaf)
+    if evicted:
+        assert not any(TaskKey(f"ten{evicted[0]}", "w", t) in snap
+                       for t in _FLEET_TASKS)
+
+
+def test_publish_reads_the_state_held_at_publish(rng):
+    """An observe that lands after the take-up and before the publish
+    reads its seq: the row published is the state the predictor then
+    holds (the observed one), not the refreshed row, and the cursor
+    still advances past it."""
+    store = PosteriorStore()
+    online = OnlinePredictor(_fit(("bwa", "idx")))
+    binding = store.bind("acme", "w", online)
+    _observe_local(online, "bwa", 4, rng)
+    _observe_local(online, "idx", 4, rng, slope=12.0)
+    real = online.change_seq
+
+    def observe_first(task):
+        if task == "bwa" and "raced" not in held:
+            _observe_local(online, "bwa", 1, rng)
+            held["raced"] = online.tasks["bwa"].nig
+        return real(task)
+    held = {}
+    online.change_seq = observe_first
+    report = FleetRefresher(store, RefreshPolicy(every_n=4)).refresh()
+    assert report.n_tasks == 2 and report.n_stale == 0
+    assert online.tasks["bwa"].nig is held["raced"]
+    snap = store.snapshot()
+    for task in ("bwa", "idx"):
+        want = bayes.nig_to_blr(online.tasks[task].nig)
+        got = snap.get(TaskKey("acme", "w", task))
+        for leaf, v in got.items():
+            assert v.tobytes() == np.asarray(want[leaf],
+                                             np.float64).tobytes(), leaf
+    assert binding.is_current()

@@ -636,3 +636,79 @@ def test_snapshot_between_evict_and_recycle_refuses_new_keys():
     assert fresh.row_of(TaskKey("c", "w3", "x0")) == 0
     with pytest.raises(KeyError):
         stale.row_of(TaskKey("c", "w3", "x0"))
+
+
+# --- stacked writes (the fleet refresh's publish) --------------------------------
+def _stacked_rows(rng, n):
+    from repro.store.compute import LEAF_SHAPES
+    return {leaf: rng.normal(size=(n,) + shape).astype(np.float32)
+            for leaf, shape in LEAF_SHAPES.items()}
+
+
+def _seeded_store(rng, n=10, block_size=4):
+    store = PosteriorStore(block_size=block_size)
+    rows = _stacked_rows(rng, n)
+    store.put_many([(f"a/w/t{i}", {k: v[i] for k, v in rows.items()})
+                    for i in range(n)])
+    return store
+
+
+def test_put_stacked_rows_equal_put_many():
+    """Existing and new keys, across blocks: the same rows, row slots,
+    generation and block generations as put_many writes."""
+    stores = [_seeded_store(np.random.default_rng(3)) for _ in range(2)]
+    keys = ["a/w/t7", "a/w/t1", "b/w/new", "a/w/t2"]
+    rows = _stacked_rows(np.random.default_rng(4), len(keys))
+    stores[0].put_many([(k, {leaf: v[i] for leaf, v in rows.items()})
+                        for i, k in enumerate(keys)])
+    stores[1].put_stacked(keys, rows)
+    a, b = stores
+    assert a.generation == b.generation
+    assert a._block_gen == b._block_gen and a._rows == b._rows
+    for key in a.task_keys():
+        for leaf, v in a.get(key).items():
+            assert v.tobytes() == b.get(key)[leaf].tobytes(), (key, leaf)
+
+
+@pytest.mark.parametrize("fault", ["shape", "duplicate", "bad_key"])
+def test_put_stacked_atomic_on_bad_input(fault):
+    store = _seeded_store(np.random.default_rng(5))
+    gen, rows, blocks = store.generation, dict(store._rows), \
+        list(store._blocks)
+    snap = store.snapshot()
+    keys = ["a/w/t0", "a/w/t5", "a/w/fresh"]
+    leaves = _stacked_rows(np.random.default_rng(6), 3)
+    if fault == "shape":
+        leaves["sigma"] = leaves["sigma"][:, :1]
+    elif fault == "duplicate":
+        keys[2] = "a/w/t0"
+    else:
+        keys[2] = "not-a-key"
+    with pytest.raises(ValueError):
+        store.put_stacked(keys, leaves)
+    assert store.generation == gen and store._rows == rows
+    assert all(x is y for x, y in zip(store._blocks, blocks))
+    assert len(store._blocks) == len(blocks)
+    assert store.snapshot() is snap
+
+
+def test_put_stacked_keeps_earlier_snapshots():
+    store = _seeded_store(np.random.default_rng(7))
+    old = store.snapshot()
+    before = {k: old.get(k) for k in store.task_keys()}
+    keys = ["a/w/t0", "a/w/t9"]
+    store.put_stacked(keys, _stacked_rows(np.random.default_rng(8), 2))
+    for k, row in before.items():
+        for leaf, v in old.get(k).items():
+            assert v.tobytes() == row[leaf].tobytes()
+    assert store.get("a/w/t0")["mu"].tobytes() != before["a/w/t0"][
+        "mu"].tobytes()
+
+
+def test_put_stacked_bumps_only_touched_blocks():
+    store = _seeded_store(np.random.default_rng(9), n=12, block_size=4)
+    gen0 = dict(store._block_gen)
+    store.put_stacked(["a/w/t9", "a/w/t1"],
+                      _stacked_rows(np.random.default_rng(10), 2))
+    assert store._block_gen == {0: store.generation, 1: gen0[1],
+                                2: store.generation}
