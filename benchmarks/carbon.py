@@ -11,7 +11,7 @@ import numpy as np
 from benchmarks.common import build_experiment, fmt_table
 from repro.sched.carbon import REGIONS, shift_workload
 from repro.sched.cluster import TARGET_MACHINES
-from repro.sched.heft import heft_schedule
+from repro.sched.fused import fused_heft_schedule
 from repro.workflow.generator import WORKFLOWS
 from repro.workflow.simulator import execute_schedule
 
@@ -37,7 +37,7 @@ def run(seed: int = 0, quiet: bool = False) -> dict:
                     return true_rt(uid, node)
                 return exp.predictors[meth].predict(
                     t.task_name, t.input_gb, exp.benches[node.name])[0]
-            sched = heft_schedule(exp.dag, nodes, pred_rt)
+            sched = fused_heft_schedule(exp.dag, nodes, pred_rt)
             res = execute_schedule(exp.dag, sched, nodes, true_rt)
             durations[(wf, meth)] = (sched.predicted_makespan / 3600.0,
                                      res.makespan / 3600.0)

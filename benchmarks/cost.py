@@ -11,7 +11,7 @@ import numpy as np
 from benchmarks.common import build_experiment, fmt_table
 from repro.sched.cluster import PAPER_MACHINES
 from repro.sched.cost import actual_cost, cost_deviation_pct, predicted_cost
-from repro.sched.heft import heft_schedule
+from repro.sched.fused import fused_heft_schedule
 from repro.workflow.generator import WORKFLOWS
 from repro.workflow.simulator import execute_schedule
 from repro.core.microbench import NodeSpec
@@ -48,7 +48,7 @@ def run(seed: int = 0, quiet: bool = False) -> dict:
                     bench = exp.benches[node.name.rsplit("-", 1)[0]]
                     return exp.predictors[meth].predict(t.task_name,
                                                         t.input_gb, bench)[0]
-                sched = heft_schedule(exp.dag, nodes, pred_rt)
+                sched = fused_heft_schedule(exp.dag, nodes, pred_rt)
                 res = execute_schedule(exp.dag, sched, nodes, true_rt)
                 for billing in ("hourly", "minute"):
                     pred_c = predicted_cost(sched, nodes, billing)

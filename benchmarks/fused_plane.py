@@ -1,25 +1,20 @@
 """Fused decision plane benchmark: what a warm fleet-scale replan round
-costs once predict -> quantile -> rank -> EFT sweep runs as a resident,
-compiled pipeline.
+costs on the host CPU once posterior rows and the cost view stay resident
+and the candidate-EFT sweep is one jitted dispatch.
 
-Three measurements over one 1000-task x 100-node planning problem:
+Two measurements:
 
-  * matrix — the PR-4 decision plane: every round re-materializes the
-    (T, N) `PredictionMatrix` (store gather + predictive dispatch +
-    factor scaling), then runs the NumPy HEFT core's per-task Python
-    loops (`heft_schedule_matrix`);
-  * fused — the resident plane: posterior rows and the (T, N) cost view
-    stay resident across rounds (dirty-row sync only), and the whole
-    candidate-EFT insertion sweep is ONE jitted dispatch
-    (`kernels.decision_plane.eft_sweep`, float64);
-  * megabatch — `replan_many` over B tenants sharing the cluster: one
+  * fused — `FusedPlane.schedule` on one 1000-task x 100-node planning
+    problem: dirty-row sync only, and the whole insertion sweep as ONE
+    jitted float64 dispatch (`kernels.decision_plane.eft_sweep`);
+  * megabatch — `replan_many` over B tenants sharing one cluster: one
     coalesced predictive dispatch + one vmapped sweep for the whole
-    fleet batch (per-replan cost = batch / B).
+    batch (per-replan cost = batch / B), against B single replans.
 
-The fused engine must be bit-identical to the matrix path — asserted
-before anything is timed.  A roofline table closes the report: the
-modeled device cost of the fused round (`perf.roofline.
-decision_plane_roofline`) vs the measured host time.
+At these sizes the scalar reference takes minutes, so the jit sweep is
+held to the NumPy sweep instead: both schedules must be bit-identical,
+asserted before anything is timed.  All times are host wall-clock on the
+machine that runs this script, not device times.
 
   PYTHONPATH=src python -m benchmarks.fused_plane
 """
@@ -27,11 +22,9 @@ from __future__ import annotations
 
 import time
 
-from benchmarks.replan_latency import _build
-from repro.perf.roofline import decision_plane_roofline
+from benchmarks.replan_latency import (_build, numpy_sweep_schedule,
+                                       same_schedule)
 from repro.sched.fused import FusedPlane, ReplanRequest, replan_many
-from repro.sched.heft import heft_schedule_matrix
-from repro.sched.plane import PredictionMatrix
 
 
 def _timed(fn) -> float:
@@ -40,37 +33,22 @@ def _timed(fn) -> float:
     return time.perf_counter() - t0
 
 
-def _schedules_equal(a, b) -> bool:
-    return (a.assignment == b.assignment and a.order == b.order
-            and a.est == b.est)
-
-
 def run(n_tasks: int = 1000, n_nodes: int = 100, seed: int = 0,
         repeats: int = 5, quantile: float = 0.95,
         batch: int = 6, batch_tasks: int = 300, batch_nodes: int = 30,
         quiet: bool = False) -> dict:
     dag, nodes, svc = _build(n_tasks, n_nodes, seed)
-    entries = [(u, dag.tasks[u].task_name, dag.tasks[u].input_gb)
-               for u in dag.tasks]
-
-    def matrix_round():
-        mat = PredictionMatrix.from_service(svc, entries, nodes)
-        return heft_schedule_matrix(dag, nodes, mat, quantile=quantile)
-
     plane = FusedPlane(svc, nodes, dag=dag)
 
     def fused_round():
         return plane.schedule(dag, quantile=quantile)
 
-    # correctness before speed: the fused engine must be bit-identical
-    want = matrix_round()
+    # correctness before speed: the jit sweep must equal the NumPy sweep
     got = fused_round()                      # also compiles the sweep
-    parity = _schedules_equal(got, want)
-    assert parity, "fused engine diverged from heft_schedule_matrix"
-
-    matrix_s = min(_timed(matrix_round) for _ in range(repeats))
+    parity = same_schedule(got, numpy_sweep_schedule(
+        dag, nodes, plane.matrix(), quantile=quantile))
+    assert parity, "jit sweep diverged from the NumPy sweep"
     fused_s = min(_timed(fused_round) for _ in range(repeats))
-    speedup = matrix_s / fused_s
 
     # megabatch: B tenants replanning one cluster in one dispatch pair
     bdag, bnodes, bsvc = _build(batch_tasks, batch_nodes, seed + 1)
@@ -79,26 +57,17 @@ def run(n_tasks: int = 1000, n_nodes: int = 100, seed: int = 0,
             for p in planes]
     replan_many(reqs)                        # warm + compile
     mega_s = min(_timed(lambda: replan_many(reqs)) for _ in range(repeats))
-    bentries = [(u, bdag.tasks[u].task_name, bdag.tasks[u].input_gb)
-                for u in bdag.tasks]
-    bmat = PredictionMatrix.from_service(bsvc, bentries, bnodes)
-    bwant = heft_schedule_matrix(bdag, bnodes, bmat, quantile=quantile)
-    mega_parity = all(_schedules_equal(s, bwant) for s in replan_many(reqs))
-    assert mega_parity, "megabatched replan diverged from the reference"
+    bwant = numpy_sweep_schedule(bdag, bnodes, planes[0].matrix(),
+                                 quantile=quantile)
+    mega_parity = all(same_schedule(s, bwant) for s in replan_many(reqs))
+    assert mega_parity, "megabatched replan diverged from the NumPy sweep"
     single_s = min(_timed(lambda: planes[0].schedule(bdag,
                                                      quantile=quantile))
                    for _ in range(repeats))
 
-    # roofline: modeled device cost of the fused pipeline vs measured host
-    dep_width = int(plane.rank_cache and next(
-        iter(plane.rank_cache.values())).dep_rows.shape[1] or 4)
-    terms = decision_plane_roofline(n_tasks, n_nodes, dep_width=dep_width)
-    achieved = terms.achieved_fraction(fused_s)
-
     out = {
         "n_tasks": n_tasks, "n_nodes": n_nodes, "quantile": quantile,
-        "matrix_s": matrix_s, "fused_s": fused_s, "speedup": speedup,
-        "bit_parity": parity,
+        "fused_s": fused_s, "bit_parity": parity,
         "megabatch": {
             "batch": batch, "n_tasks": batch_tasks, "n_nodes": batch_nodes,
             "batch_s": mega_s, "per_replan_s": mega_s / batch,
@@ -109,38 +78,17 @@ def run(n_tasks: int = 1000, n_nodes: int = 100, seed: int = 0,
             "sweep_dispatches": planes[0].stats.sweep_dispatches,
         },
         "plane_stats": vars(plane.stats),
-        "roofline": {**terms.to_dict(),
-                     "measured_host_s": fused_s,
-                     "achieved_fraction": achieved},
     }
     if not quiet:
         print(f"Warm replan round ({n_tasks} tasks x {n_nodes} nodes, "
-              f"q={quantile}):")
-        print(f"  matrix path   {matrix_s * 1e3:8.2f} ms")
-        print(f"  fused plane   {fused_s * 1e3:8.2f} ms   "
-              f"-> {speedup:.1f}x")
-        print(f"Megabatch ({batch} x {batch_tasks}x{batch_nodes}): "
-              f"{mega_s * 1e3:.2f} ms batch, "
+              f"q={quantile}), host wall-clock: {fused_s * 1e3:.2f} ms")
+        print(f"Megabatch ({batch} x {batch_tasks}x{batch_nodes}), host "
+              f"wall-clock: {mega_s * 1e3:.2f} ms batch, "
               f"{mega_s / batch * 1e3:.2f} ms/replan "
               f"(single {single_s * 1e3:.2f} ms -> "
               f"{single_s * batch / mega_s:.1f}x)")
-        r = out["roofline"]
-        print("Roofline (fused round, modeled device vs measured host):")
-        print("  term          value")
-        print(f"  flops         {r['flops']:.3e}")
-        print(f"  hbm_bytes     {r['hbm_bytes']:.3e}")
-        print(f"  t_compute     {r['t_compute'] * 1e6:10.2f} us")
-        print(f"  t_memory      {r['t_memory'] * 1e6:10.2f} us")
-        print(f"  device (mod)  {r['device_time_model'] * 1e6:10.2f} us "
-              f"[{r['bottleneck']}-bound]")
-        print(f"  host (meas)   {fused_s * 1e6:10.2f} us   "
-              f"achieved {achieved:.4f} of roofline")
-        print(f"[claim] fused replan >= 10x over the matrix path -> "
-              f"{'PASS' if speedup >= 10.0 else 'FAIL'}")
-        print(f"[claim] bit-identical schedules -> "
+        print(f"[claim] bit-identical schedules (jit vs NumPy sweep) -> "
               f"{'PASS' if parity and mega_parity else 'FAIL'}")
-        print(f"[claim] modeled device replan < 1 ms -> "
-              f"{'PASS' if terms.device_time < 1e-3 else 'FAIL'}")
     return out
 
 

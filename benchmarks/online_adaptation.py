@@ -29,7 +29,7 @@ from repro.online import (OnlinePredictor, OnlineReschedulingPlanner,
                           PredictionService, TaskCompletion)
 from repro.online.events import PredictionQuery
 from repro.sched.cluster import TARGET_MACHINES
-from repro.sched.heft import heft_schedule
+from repro.sched.fused import fused_heft_schedule
 from repro.workflow.generator import WORKFLOWS
 from repro.workflow.simulator import execute_adaptive, execute_schedule
 
@@ -65,7 +65,7 @@ def run_error_decay(seed: int = 0, quiet: bool = False) -> dict:
         pred_rt = lambda u, n: lot.predict(
             exp.dag.tasks[u].task_name, exp.dag.tasks[u].input_gb,
             exp.benches[n.name])[0]
-        sched = heft_schedule(exp.dag, nodes, pred_rt)
+        sched = fused_heft_schedule(exp.dag, nodes, pred_rt)
         recs = sorted(execute_schedule(exp.dag, sched, nodes, true_rt).records,
                       key=lambda r: r.finish)
         online = OnlinePredictor(lot, benches=exp.benches)
@@ -117,13 +117,15 @@ def run_makespan_recovery(seed: int = 0, quiet: bool = False) -> dict:
             exp.dag.tasks[u].task_name, exp.dag.tasks[u].input_gb,
             exp.benches[n.name])[0]
         static = execute_schedule(
-            exp.dag, heft_schedule(exp.dag, nodes, pred_rt), nodes, true_rt)
+            exp.dag, fused_heft_schedule(exp.dag, nodes, pred_rt), nodes,
+            true_rt)
         online = OnlinePredictor(lot, benches=exp.benches)
         planner = OnlineReschedulingPlanner(exp.dag, nodes, online,
                                             benches=exp.benches)
         adaptive = execute_adaptive(exp.dag, nodes, planner, true_rt)
         oracle = execute_schedule(
-            exp.dag, heft_schedule(exp.dag, nodes, true_rt), nodes, true_rt)
+            exp.dag, fused_heft_schedule(exp.dag, nodes, true_rt), nodes,
+            true_rt)
         out[wf] = {"static": static.makespan, "adaptive": adaptive.makespan,
                    "oracle": oracle.makespan,
                    "reschedules": adaptive.n_reschedules}

@@ -31,7 +31,7 @@ from repro.core import bayes
 from repro.online import (FleetRefresher, OnlinePredictor, PredictionService,
                           RefreshPolicy, TaskCompletion)
 from repro.sched.cluster import LOCAL, TARGET_MACHINES
-from repro.sched.heft import heft_schedule
+from repro.sched.fused import fused_heft_schedule
 from repro.workflow.simulator import execute_schedule
 
 N_TASKS_PER_TENANT = 40
@@ -190,7 +190,7 @@ def run_adaptation_gain(seed: int = 0, quiet: bool = False) -> dict:
     pred_rt = lambda u, n: lot.predict(
         exp.dag.tasks[u].task_name, exp.dag.tasks[u].input_gb,
         resolve_bench(exp.benches, n.name))[0]
-    sched = heft_schedule(exp.dag, nodes, pred_rt)
+    sched = fused_heft_schedule(exp.dag, nodes, pred_rt)
     recs = sorted(execute_schedule(exp.dag, sched, nodes, true_rt).records,
                   key=lambda r: r.finish)
     half = int(0.6 * len(recs))

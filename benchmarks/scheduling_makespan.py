@@ -11,7 +11,7 @@ import numpy as np
 
 from benchmarks.common import ALL_METHODS, build_experiment, fmt_table
 from repro.sched.cluster import TARGET_MACHINES
-from repro.sched.heft import heft_schedule
+from repro.sched.fused import fused_heft_schedule
 from repro.workflow.dag import WorkflowDAG
 from repro.workflow.generator import WORKFLOWS, build_workflow
 from repro.workflow.simulator import execute_schedule, random_cluster
@@ -64,7 +64,7 @@ def run(n_clusters: int = 60, seed: int = 0, quiet: bool = False) -> dict:
                 bench = e.benches[node.name.rsplit("-", 1)[0]]
                 return e.predictors[meth].predict(t.task_name, t.input_gb,
                                                   bench)[0]
-            sched = heft_schedule(dag, nodes, pred_rt)
+            sched = fused_heft_schedule(dag, nodes, pred_rt)
             res = execute_schedule(dag, sched, nodes, true_rt)
             makespans[meth] = res.makespan
         best = min(makespans.values())

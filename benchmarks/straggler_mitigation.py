@@ -29,7 +29,7 @@ import numpy as np
 from benchmarks.common import build_experiment, fmt_table
 from repro.online import OnlinePredictor, OnlineReschedulingPlanner
 from repro.sched.cluster import TARGET_MACHINES
-from repro.sched.heft import heft_schedule
+from repro.sched.fused import fused_heft_schedule
 from repro.sched.straggler import straggler_threshold
 from repro.store import resolve_bench
 from repro.workflow.simulator import (SpeculationPolicy, execute_adaptive,
@@ -53,7 +53,7 @@ def run(straggler_frac: float = 0.08, factor: float = 5.0, seed: int = 0,
         return exp.predictors["lotaru-g"].predict(
             t.task_name, t.input_gb, resolve_bench(exp.benches, node.name))
 
-    sched = heft_schedule(exp.dag, nodes, lambda u, n: pred(u, n)[0])
+    sched = fused_heft_schedule(exp.dag, nodes, lambda u, n: pred(u, n)[0])
     ideal = execute_schedule(exp.dag, sched, nodes, true_rt).makespan
 
     results = {}

@@ -20,9 +20,10 @@ plain float64 reference:
            buffers.
   ingest   2,000 records over 4 tenants through observe_many (host
            float64 by contract): state digests equal the scalar chain.
-  replan   FusedPlane.schedule on a 1000-task x 100-node DAG through the
-           jit engine, and replan_many over 8 tenants sharing the cluster:
-           schedules bitwise equal to heft_schedule_matrix.
+  replan   FusedPlane.schedule on a 1000-task x 100-node DAG (the jit
+           sweep at this size), and replan_many over 8 tenants sharing the
+           cluster: schedules bitwise equal to the NumPy sweep's, or to
+           heft_schedule_reference below the jit sweep's size.
 
 Each phase prints one JSON line: the device it ran on, compile seconds
 (JAX's own trace, lowering and compile-or-cache-fetch events), the wall
@@ -55,7 +56,9 @@ import numpy as np  # noqa: E402
 
 from benchmarks.common import build_experiment  # noqa: E402
 from benchmarks.ingest_throughput import _fleet, _stream  # noqa: E402
-from benchmarks.replan_latency import _build  # noqa: E402
+from benchmarks.replan_latency import (_build,  # noqa: E402
+                                       numpy_sweep_schedule,
+                                       reference_schedule)
 from repro.core import bayes  # noqa: E402
 from repro.kernels import ops  # noqa: E402
 from repro.kernels.bayes_fit import (bayes_fit_ragged,  # noqa: E402
@@ -64,9 +67,9 @@ from repro.online import (FleetRefresher, OnlinePredictor,  # noqa: E402
                           PredictionQuery, PredictionService, RefreshPolicy,
                           TaskCompletion)
 from repro.sched.cluster import LOCAL, TARGET_MACHINES  # noqa: E402
+from repro.sched import fused  # noqa: E402
 from repro.sched.fused import (FusedPlane, ReplanRequest,  # noqa: E402
                                replan_many, sweep_device)
-from repro.sched.heft import heft_schedule_matrix  # noqa: E402
 from repro.serve import state_digest  # noqa: E402
 from repro.store import AsyncPredictionFrontend, PosteriorStore  # noqa: E402
 from repro.store.compute import fit_stacked, scale  # noqa: E402
@@ -424,14 +427,24 @@ def _schedule_diff(got, want) -> tuple:
     return moved, err
 
 
+def _oracle(dag, nodes, mat, quantile):
+    """The schedule a replan must equal bitwise.  Below the jit sweep's
+    size the engine sweeps in NumPy, and the scalar reference is cheap:
+    check against it.  At fleet size the reference takes minutes: check
+    the jit sweep against the NumPy sweep on the same costs."""
+    if len(dag.tasks) * len(nodes) < fused._JIT_MIN_CELLS:
+        return "reference", reference_schedule(dag, nodes, mat, quantile)
+    return "numpy", numpy_sweep_schedule(dag, nodes, mat, quantile)
+
+
 def phase_replan(sizes: Sizes, seed: int) -> dict:
     dag, nodes, svc = _build(sizes.replan_tasks, sizes.replan_nodes, seed)
     q = REPLAN_QUANTILES[0]
     plane = FusedPlane(svc, nodes, dag=dag)
-    _, cold = _timed(lambda: plane.schedule(dag, quantile=q, engine="jit"))
-    got, warm = _timed(lambda: plane.schedule(dag, quantile=q, engine="jit"))
-    pairs = [(got, heft_schedule_matrix(dag, nodes, plane.matrix(),
-                                        quantile=q))]
+    _, cold = _timed(lambda: plane.schedule(dag, quantile=q))
+    got, warm = _timed(lambda: plane.schedule(dag, quantile=q))
+    oracle, want = _oracle(dag, nodes, plane.matrix(), q)
+    pairs = [(got, want)]
 
     reqs = [ReplanRequest(
         plane=FusedPlane(PredictionService(svc.predictor, svc.benches,
@@ -442,8 +455,7 @@ def phase_replan(sizes: Sizes, seed: int) -> dict:
         dag=dag, quantile=qq) for i, qq in enumerate(REPLAN_QUANTILES)]
     _, many_cold = _timed(lambda: replan_many(reqs))
     scheds, many_warm = _timed(lambda: replan_many(reqs))
-    pairs += [(s, heft_schedule_matrix(dag, nodes, r.plane.matrix(),
-                                       quantile=r.quantile))
+    pairs += [(s, _oracle(dag, nodes, r.plane.matrix(), r.quantile)[1])
               for s, r in zip(scheds, reqs)]
     diffs = [_schedule_diff(g, w) for g, w in pairs]
     moved = sum(d[0] for d in diffs)
@@ -452,6 +464,7 @@ def phase_replan(sizes: Sizes, seed: int) -> dict:
             "tenants": len(reqs),
             "sweep_device": sweep_device().platform,
             "sweep_dispatches": reqs[0].plane.stats.sweep_dispatches,
+            "oracle": oracle,
             "cold_s": cold, "warm_s": warm,
             "many_cold_s": many_cold, "many_warm_s": many_warm,
             "moved": moved, "max_err": err, "tol": 0.0,

@@ -17,7 +17,7 @@ from repro.online import (OnlinePredictor, OnlineReschedulingPlanner,
                           PredictionService)
 from repro.online.events import PredictionQuery
 from repro.sched.cluster import TARGET_MACHINES
-from repro.sched.heft import heft_schedule
+from repro.sched.fused import fused_heft_schedule
 from repro.workflow.simulator import execute_adaptive, execute_schedule
 
 DRIFT = {"A1": 1.5, "N2": 0.6, "C2": 2.0}   # true-runtime multiplier
@@ -53,14 +53,16 @@ def main():
     pred_rt = lambda u, n: lot.predict(exp.dag.tasks[u].task_name,
                                        exp.dag.tasks[u].input_gb,
                                        exp.benches[n.name])[0]
-    static = execute_schedule(exp.dag, heft_schedule(exp.dag, nodes, pred_rt),
-                              nodes, true_rt)
+    static = execute_schedule(
+        exp.dag, fused_heft_schedule(exp.dag, nodes, pred_rt), nodes,
+        true_rt)
     online = OnlinePredictor(lot, benches=exp.benches)
     planner = OnlineReschedulingPlanner(exp.dag, nodes, online,
                                         benches=exp.benches)
     adaptive = execute_adaptive(exp.dag, nodes, planner, true_rt)
-    oracle = execute_schedule(exp.dag, heft_schedule(exp.dag, nodes, true_rt),
-                              nodes, true_rt)
+    oracle = execute_schedule(
+        exp.dag, fused_heft_schedule(exp.dag, nodes, true_rt), nodes,
+        true_rt)
 
     print(f"\nstatic schedule makespan:   {static.makespan / 60:7.1f}m")
     print(f"adaptive (online) makespan: {adaptive.makespan / 60:7.1f}m "

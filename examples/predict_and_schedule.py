@@ -15,7 +15,7 @@ from benchmarks.common import ALL_METHODS, build_experiment
 from repro.core.extrapolation import extrapolate_roofline
 from repro.sched.carbon import REGIONS, shift_workload
 from repro.sched.cluster import TARGET_MACHINES, TPU_FLEET
-from repro.sched.heft import heft_schedule
+from repro.sched.fused import fused_heft_schedule
 from repro.workflow.simulator import execute_schedule
 
 
@@ -37,11 +37,12 @@ def main():
             t = exp.dag.tasks[u]
             return pred.predict(t.task_name, t.input_gb,
                                 exp.benches[n.name])[0]
-        sched = heft_schedule(exp.dag, nodes, pred_rt)
+        sched = fused_heft_schedule(exp.dag, nodes, pred_rt)
         res = execute_schedule(exp.dag, sched, nodes, true_rt)
         rows[meth] = (sched.predicted_makespan, res.makespan)
-    ms_true = execute_schedule(exp.dag, heft_schedule(exp.dag, nodes, true_rt),
-                               nodes, true_rt).makespan
+    ms_true = execute_schedule(
+        exp.dag, fused_heft_schedule(exp.dag, nodes, true_rt), nodes,
+        true_rt).makespan
     print(f"{'method':10s} {'predicted':>10s} {'actual':>10s} {'vs perfect':>11s}")
     for meth, (pm, am) in rows.items():
         print(f"{meth:10s} {pm/60:9.1f}m {am/60:9.1f}m "

@@ -13,7 +13,7 @@ import numpy as np
 from repro.core.microbench import run_local_microbench, simulate_microbench
 from repro.core.predictor import LotaruPredictor
 from repro.sched.cluster import LOCAL, TARGET_MACHINES
-from repro.sched.heft import heft_schedule
+from repro.sched.fused import fused_heft_schedule
 from repro.workflow.generator import GroundTruth, build_workflow
 from repro.workflow.profiling import local_profiling
 from repro.workflow.simulator import execute_schedule
@@ -51,9 +51,9 @@ def main():
     pred_rt = lambda u, n: lot.predict(dag.tasks[u].task_name,
                                        dag.tasks[u].input_gb,
                                        benches[n.name])[0]
-    ms_pred = execute_schedule(dag, heft_schedule(dag, nodes, pred_rt),
+    ms_pred = execute_schedule(dag, fused_heft_schedule(dag, nodes, pred_rt),
                                nodes, true_rt).makespan
-    ms_true = execute_schedule(dag, heft_schedule(dag, nodes, true_rt),
+    ms_true = execute_schedule(dag, fused_heft_schedule(dag, nodes, true_rt),
                                nodes, true_rt).makespan
     print(f"   makespan with lotaru predictions: {ms_pred/60:.1f} min")
     print(f"   makespan with perfect knowledge:  {ms_true/60:.1f} min "

@@ -353,17 +353,14 @@ def nig_update_batch(nigs, xs, ys, impl: str = "numpy"):
     observation sequence for state i, in arrival order.  Returns T updated
     states; the inputs are not mutated.
 
-    impl='numpy' (default) is the float64 CPU path the ingest plane uses —
-    bit-identical to `[chain of nig_update]` per task (the scalar chain is
-    the exactness oracle).  It size-dispatches between two forms that run
-    the identical IEEE op sequence: 'chain' (per-task python-float chains;
-    fastest when T is small, where numpy per-op overhead dominates) and
-    'vec' (the masked (T, K) vectorized fold `_nig_fold_np`; fastest for
-    wide cross-task batches).  Pass 'chain'/'vec' to force a form.
-    impl='scan' runs the vmapped `lax.scan` form and 'pallas'/'interpret'
-    the fused kernel (kernels.bayes_fit.nig_fold) — the device-resident
-    float32 forms for TPU posterior banks, parity within kernel tolerance,
-    NOT for the float64 streaming states that feed digests.
+    The fold is float64 on the host and bit-identical to `[chain of
+    nig_update]` per task (the scalar chain is the exactness oracle):
+    digests and failover replay depend on that.  impl='numpy' (default)
+    size-dispatches between two forms that run the identical IEEE op
+    sequence: 'chain' (per-task python-float chains; fastest when T is
+    small, where numpy per-op overhead dominates) and 'vec' (the masked
+    (T, K) vectorized fold `_nig_fold_np`; fastest for wide cross-task
+    batches).  Pass 'chain'/'vec' to force a form; any other impl raises.
     """
     if len(xs) != len(nigs) or len(ys) != len(nigs):
         raise ValueError(f"need one observation row per state: "
@@ -383,6 +380,8 @@ def nig_update_batch(nigs, xs, ys, impl: str = "numpy"):
     if impl == "chain":
         return [_nig_chain_py(n, xr, yr)
                 for n, xr, yr in zip(nigs, xs, ys)]
+    if impl != "vec":
+        raise ValueError(f"unknown impl {impl!r}")
 
     x = np.zeros((t, kmax), np.float64)
     y = np.zeros((t, kmax), np.float64)
@@ -404,27 +403,8 @@ def nig_update_batch(nigs, xs, ys, impl: str = "numpy"):
     b = np.array([n["b"] for n in nigs], np.float64)
     n_obs = np.array([n["n_obs"] for n in nigs], np.float64)
 
-    if impl == "vec":
-        mu, v, prec, a, b, n_obs = _nig_fold_np(mu, v, prec, a, b, n_obs,
-                                                sx, sy, m)
-    elif impl in ("scan", "pallas", "interpret", "auto"):
-        from repro.kernels import bayes_fit as _kbf
-        if impl == "scan":
-            fmu, fv, fprec, fb = _kbf.nig_fold_scan(
-                sx, sy, m, mu, v, prec, b)
-        else:
-            fmu, fv, fprec, fb = _kbf.nig_fold(
-                sx, sy, m, mu, v, prec, b,
-                interpret=(impl == "interpret"))
-        counts = m.sum(axis=1)
-        mu = np.asarray(fmu, np.float64)
-        v = np.asarray(fv, np.float64)
-        prec = np.asarray(fprec, np.float64)
-        b = np.asarray(fb, np.float64)
-        a = a + 0.5 * counts
-        n_obs = n_obs + counts
-    else:
-        raise ValueError(f"unknown impl {impl!r}")
+    mu, v, prec, a, b, n_obs = _nig_fold_np(mu, v, prec, a, b, n_obs,
+                                            sx, sy, m)
 
     counts = m.sum(axis=1)
     out = []

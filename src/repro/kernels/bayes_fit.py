@@ -14,7 +14,6 @@ beta_prec, and the standardization stats — matching core.bayes.fit_blr
 """
 from __future__ import annotations
 
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -169,146 +168,6 @@ def bayes_fit_ragged(x: jnp.ndarray, y: jnp.ndarray, mask: jnp.ndarray, *,
         mask = jnp.pad(mask, pad)
     post = bayes_fit(x, y, mask, block_tasks=bt, interpret=interpret)
     return {k: v[:t] for k, v in post.items()}
-
-
-# ---------------------------------------------------------------------------
-# batched streaming-update fold (the ingest-plane hot path)
-# ---------------------------------------------------------------------------
-# One ingest batch = K completions spanning T tasks.  The scalar path pays
-# one Sherman-Morrison rank-1 update per completion; the fold applies each
-# task's observation sequence in order, but runs ALL tasks' sequences
-# simultaneously — a (T, K) masked scan where step k advances every task
-# that still has a k-th observation.  All 2x2 algebra is unrolled to
-# elementwise component arithmetic, so one grid step is K rounds of
-# vector ops over a (block_tasks,) tile: one HBM read of the tile, one
-# write of the folded states.  Inputs are PRE-standardized (the caller
-# owns the frozen affine coords); a and n_obs are closed-form in the mask
-# counts and stay host-side.
-
-DEFAULT_FOLD_COLS = 8
-
-
-def _nig_fold_kernel(x_ref, y_ref, m_ref, mu_ref, v_ref, prec_ref, b_ref,
-                     omu_ref, ov_ref, oprec_ref, ob_ref):
-    xs = x_ref[...]                                # (bt, K) standardized
-    ys = y_ref[...]
-    m = m_ref[...]
-    mu1, mu2 = mu_ref[...][:, 0], mu_ref[...][:, 1]
-    v = v_ref[...]                                 # (bt, 4) [00,01,10,11]
-    v11, v12, v22 = v[:, 0], v[:, 1], v[:, 3]
-    p = prec_ref[...]
-    p11, p12, p22 = p[:, 0], p[:, 1], p[:, 3]
-    b = b_ref[...][:, 0]
-
-    for k in range(xs.shape[1]):                   # K is static: unrolled
-        xk, yk, mk = xs[:, k], ys[:, k], m[:, k]
-        # vp = V phi with phi = (1, xk)
-        vp1 = v11 + v12 * xk
-        vp2 = v12 + v22 * xk
-        denom = 1.0 + (vp1 + xk * vp2)             # 1 + phi^T V phi
-        nv11 = v11 - vp1 * vp1 / denom
-        nv12 = v12 - vp1 * vp2 / denom
-        nv22 = v22 - vp2 * vp2 / denom
-        np11 = p11 + 1.0
-        np12 = p12 + xk
-        np22 = p22 + xk * xk
-        r1 = (p11 * mu1 + p12 * mu2) + yk          # prec mu + phi y
-        r2 = (p12 * mu1 + p22 * mu2) + xk * yk
-        nmu1 = nv11 * r1 + nv12 * r2
-        nmu2 = nv12 * r1 + nv22 * r2
-        qo = (mu1 * p11 + mu2 * p12) * mu1 + (mu1 * p12 + mu2 * p22) * mu2
-        qn = (nmu1 * np11 + nmu2 * np12) * nmu1 \
-            + (nmu1 * np12 + nmu2 * np22) * nmu2
-        nb = jnp.maximum(b + 0.5 * (yk * yk + qo - qn), 1e-12)
-        sel = mk > 0.0
-        mu1 = jnp.where(sel, nmu1, mu1)
-        mu2 = jnp.where(sel, nmu2, mu2)
-        v11 = jnp.where(sel, nv11, v11)
-        v12 = jnp.where(sel, nv12, v12)
-        v22 = jnp.where(sel, nv22, v22)
-        p11 = jnp.where(sel, np11, p11)
-        p12 = jnp.where(sel, np12, p12)
-        p22 = jnp.where(sel, np22, p22)
-        b = jnp.where(sel, nb, b)
-
-    omu_ref[...] = jnp.stack([mu1, mu2], axis=1)
-    ov_ref[...] = jnp.stack([v11, v12, v12, v22], axis=1)
-    oprec_ref[...] = jnp.stack([p11, p12, p12, p22], axis=1)
-    ob_ref[...] = b[:, None]
-
-
-def nig_fold(xs, ys, mask, mu, v, prec, b, *,
-             block_tasks: int = DEFAULT_BLOCK_TASKS,
-             col_bucket: int = DEFAULT_FOLD_COLS,
-             interpret: bool = False):
-    """Fused masked fold of (T, K) standardized observations into T NIG
-    states.  mu: (T,2); v, prec: (T,2,2); b: (T,).  Returns the updated
-    (mu, v, prec, b).  Columns are bucketed (the kernel unrolls K) and the
-    task dim padded to a block multiple, so ragged ingest batches of any
-    shape cost one pallas_call."""
-    t, k = np.shape(xs)
-    kp = max(1, -(-k // col_bucket) * col_bucket)
-    bt = min(block_tasks, max(t, 1))
-    tp = -(-t // bt) * bt
-
-    def pad(arr, cols=None):
-        arr = jnp.asarray(arr, jnp.float32).reshape(t, -1)
-        want = cols if cols is not None else arr.shape[1]
-        return jnp.pad(arr, ((0, tp - t), (0, want - arr.shape[1])))
-
-    xq, yq, mq = pad(xs, kp), pad(ys, kp), pad(mask, kp)
-    muq = pad(jnp.asarray(mu).reshape(t, 2))
-    vq = pad(jnp.asarray(v).reshape(t, 4))
-    pq = pad(jnp.asarray(prec).reshape(t, 4))
-    bq = pad(jnp.asarray(b).reshape(t, 1))
-
-    obs_spec = pl.BlockSpec((bt, kp), lambda i: (i, 0))
-    two = pl.BlockSpec((bt, 2), lambda i: (i, 0))
-    four = pl.BlockSpec((bt, 4), lambda i: (i, 0))
-    one = pl.BlockSpec((bt, 1), lambda i: (i, 0))
-    omu, ov, oprec, ob = pl.pallas_call(
-        _nig_fold_kernel,
-        grid=(tp // bt,),
-        in_specs=[obs_spec, obs_spec, obs_spec, two, four, four, one],
-        out_specs=[two, four, four, one],
-        out_shape=[jax.ShapeDtypeStruct((tp, 2), jnp.float32),
-                   jax.ShapeDtypeStruct((tp, 4), jnp.float32),
-                   jax.ShapeDtypeStruct((tp, 4), jnp.float32),
-                   jax.ShapeDtypeStruct((tp, 1), jnp.float32)],
-        interpret=interpret,
-    )(xq, yq, mq, muq, vq, pq, bq)
-    return (omu[:t], ov[:t].reshape(t, 2, 2),
-            oprec[:t].reshape(t, 2, 2), ob[:t, 0])
-
-
-@jax.jit
-def nig_fold_scan(xs, ys, mask, mu, v, prec, b):
-    """vmapped per-task sequential `lax.scan` form of the fold — the jit
-    reference for the kernel, and the dispatch-friendly shape for chaining
-    the fold into larger jitted programs.  Same signature as `nig_fold`."""
-    def one(xr, yr, mr, mu0, v0, p0, b0):
-        def step(carry, inp):
-            cmu, cv, cp, cb = carry
-            xk, yk, mk = inp
-            phi = jnp.stack([jnp.ones_like(xk), xk])
-            vp = cv @ phi
-            denom = 1.0 + phi @ vp
-            v_n = cv - jnp.outer(vp, vp) / denom
-            p_n = cp + jnp.outer(phi, phi)
-            mu_n = v_n @ (cp @ cmu + phi * yk)
-            b_n = jnp.maximum(
-                cb + 0.5 * (yk * yk + cmu @ cp @ cmu - mu_n @ p_n @ mu_n),
-                1e-12)
-            sel = mk > 0.0
-            return (jnp.where(sel, mu_n, cmu), jnp.where(sel, v_n, cv),
-                    jnp.where(sel, p_n, cp), jnp.where(sel, b_n, cb)), 0.0
-        (muf, vf, pf, bf), _ = jax.lax.scan(
-            step, (mu0, v0, p0, b0), (xr, yr, mr))
-        return muf, vf, pf, bf
-
-    f32 = lambda z: jnp.asarray(z, jnp.float32)
-    return jax.vmap(one)(f32(xs), f32(ys), f32(mask),
-                         f32(mu), f32(v), f32(prec), f32(b))
 
 
 # ---------------------------------------------------------------------------

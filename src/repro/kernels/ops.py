@@ -1,6 +1,10 @@
 """jit'd public wrappers for the Pallas kernels, with platform dispatch:
 TPU -> compiled kernel; CPU -> interpret mode (tests) or the jnp reference
-(production fallback).  The model code calls these entry points."""
+(production fallback).  The serving stack calls `bayes_predict` (through
+`store.compute.predict_stacked`); `flash_attention`, `rglru_scan` and
+`bayes_fit` are reached by tests only.  The decision plane's sweep
+(`kernels.decision_plane`) carries loop state and keeps its own jit entry
+points."""
 from __future__ import annotations
 
 import functools
@@ -14,10 +18,6 @@ from repro.kernels import ref
 from repro.kernels.bayes_fit import bayes_fit as _bayes_fit_pallas
 from repro.kernels.bayes_fit import (bayes_predict_planes,
                                      pack_predict_planes)
-from repro.kernels.bayes_fit import nig_fold as _nig_fold_pallas
-from repro.kernels.bayes_fit import nig_fold_scan as _nig_fold_scan
-from repro.kernels.decision_plane import fused_cost as _fused_cost_pallas
-from repro.kernels.decision_plane import fused_cost_ref as _fused_cost_ref
 from repro.kernels.flash_attention import flash_attention as _flash_pallas
 from repro.kernels.rglru_scan import rglru_scan as _rglru_pallas
 
@@ -56,20 +56,6 @@ def bayes_fit(x, y, mask, *, impl: str = "auto"):
     return ref.bayes_fit_ref(x, y, mask)
 
 
-def nig_fold(xs, ys, mask, mu, v, prec, b, *, impl: str = "auto"):
-    """Batched streaming-update fold (the ingest-plane device form):
-    (T, K) standardized masked observations folded into T NIG states in
-    one dispatch.  impl: auto | pallas | interpret | ref ('ref' is the
-    vmapped lax.scan form).  The EXACT float64 ingest path lives in
-    `core.bayes.nig_update_batch(impl='numpy')` — this float32 entry point
-    is for device-resident posterior banks, not digest-bearing state."""
-    if impl == "pallas" or (impl == "auto" and _on_tpu()):
-        return _nig_fold_pallas(xs, ys, mask, mu, v, prec, b)
-    if impl == "interpret":
-        return _nig_fold_pallas(xs, ys, mask, mu, v, prec, b, interpret=True)
-    return _nig_fold_scan(xs, ys, mask, mu, v, prec, b)
-
-
 @functools.partial(jax.jit, static_argnames=("impl",))
 def _bayes_predict_jit(planes, impl: str):
     """Packed planes (PREDICT_PLANES, rows, LANE) -> (2, rows, LANE): mean
@@ -86,21 +72,6 @@ def _bayes_predict_jit(planes, impl: str):
             "y_mu": p[9], "y_sd": p[10]}
     mean, std = ref.bayes_predict_ref(p[0], post)
     return jnp.stack([mean, std]).reshape((2,) + planes.shape[1:])
-
-
-@functools.partial(jax.jit, static_argnames=("z", "impl"))
-def fused_cost(x, post, factors, *, z: float = 0.0, impl: str = "auto"):
-    """Fused predict -> scale -> quantile cost matrix (T, N) for the
-    decision plane: posterior rows + input sizes + factor matrix in, the
-    HEFT cost matrix out, one dispatch.  impl: auto | pallas | interpret
-    | ref.  The EFT sweep itself lives in `kernels.decision_plane`
-    (`eft_sweep` / `eft_sweep_many` / `eft_sweep_pallas`) — it carries
-    loop state, so it keeps its own jit entry points."""
-    if impl == "pallas" or (impl == "auto" and _on_tpu()):
-        return _fused_cost_pallas(x, post, factors, z=z)
-    if impl == "interpret":
-        return _fused_cost_pallas(x, post, factors, z=z, interpret=True)
-    return _fused_cost_ref(x, post, factors, z)
 
 
 def bayes_predict(x, post, *, impl: str = "auto"):

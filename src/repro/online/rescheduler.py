@@ -10,14 +10,14 @@ data already produced constrains ready times (finish + comm from the
 producing node to each candidate).
 
 Every planning pass goes through the decision plane, and the plane is
-device-resident: a `FusedPlane` keeps the raw predictive rows for the
-whole workflow across passes and re-gathers only the rows whose store
-blocks moved (generation-tagged dirty tracking), so a planning pass costs
-a dirty-subset predict — not a full gather — plus the fused HEFT engine
-(`sched.fused.fused_heft_schedule`, bit-identical to
-`heft.heft_schedule_matrix`; small frontiers take the NumPy sweep, large
-ones one jitted dispatch).  The drift bands and the speculation policy
-read the same resident matrix.
+resident: a `FusedPlane` keeps the raw predictive rows for the whole
+workflow across passes and re-gathers only the rows whose store blocks
+moved (generation-tagged dirty tracking), so a planning pass costs a
+dirty-subset predict — not a full gather — plus the one HEFT engine
+(`sched.fused.fused_heft_schedule`, bitwise equal to
+`heft.heft_schedule_reference`; small frontiers take the NumPy sweep,
+large ones one jitted dispatch, chosen by size).  The drift bands and the
+speculation policy read the same resident matrix.
 
 With `repro.obs` on, a completion is the span `lotaru.plan.completion`,
 holding `lotaru.plan.observe` (the posterior update), `lotaru.plan.drift`
@@ -61,8 +61,7 @@ class OnlineReschedulingPlanner:
                  z: float = 1.96, cooldown: int = 0,
                  store=None, tenant: str = "default",
                  workflow: Optional[str] = None,
-                 quantile: Optional[float] = None,
-                 engine: str = "auto"):
+                 quantile: Optional[float] = None):
         """z: band half-width in predictive stds; cooldown: minimum
         completions between two re-planning passes (0 = none); store: a
         shared PosteriorStore so several concurrent workflows/tenants serve
@@ -71,8 +70,7 @@ class OnlineReschedulingPlanner:
         when executing the same workflow type concurrently, or a later
         planner displaces the earlier one's binding); quantile: schedule on
         the pessimistic mean + z*std at this quantile instead of the mean
-        (uncertainty-aware HEFT); engine: the fused HEFT sweep engine
-        ('auto' | 'numpy' | 'jit' — all bit-identical, see sched.fused)."""
+        (uncertainty-aware HEFT)."""
         self.dag = dag
         self.nodes = nodes
         self.online = online
@@ -88,7 +86,6 @@ class OnlineReschedulingPlanner:
         self.z = z
         self.cooldown = cooldown
         self.quantile = quantile
-        self.engine = engine
         # device-resident decision plane over the WHOLE workflow: planning
         # passes re-gather only dirty rows; frontier matrices are row
         # subsets of the resident one (elementwise per row -> bitwise
@@ -147,7 +144,6 @@ class OnlineReschedulingPlanner:
             sched = fused_heft_schedule(self.dag, self.nodes, mat,
                                         quantile=self.quantile,
                                         rank_cache=self._plane.rank_cache,
-                                        engine=self.engine,
                                         dep_width=self._dep_width)
         self._band.clear()
         self._snapshot_bands(mat, sched.assignment)
@@ -266,7 +262,6 @@ class OnlineReschedulingPlanner:
                                         ready_at=ready_at,
                                         node_available=node_avail,
                                         rank_cache=self._plane.rank_cache,
-                                        engine=self.engine,
                                         dep_width=self._dep_width)
         self._snapshot_bands(mat, new_sched.assignment, frontier)
         return new_sched

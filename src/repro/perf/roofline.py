@@ -13,7 +13,7 @@ counted 2x for its two ring phases).
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Dict, Optional
+from typing import Dict
 
 # hardware constants (task spec): 197 TFLOP/s bf16; 819 GB/s HBM; ~50 GB/s/link
 PEAK_FLOPS = 197e12
@@ -102,87 +102,3 @@ def model_flops(n_active_params: float, tokens: int, kind: str) -> float:
     """MODEL_FLOPS = 6*N*D (train) / 2*N*D (forward-only serve)."""
     mult = 6.0 if kind == "train" else 2.0
     return mult * n_active_params * tokens
-
-
-@dataclass
-class DecisionPlaneTerms:
-    """Roofline terms for ONE fused replan round (predict -> quantile ->
-    rank -> EFT sweep) at (T tasks, N nodes, D dep width, S slots).
-
-    The model is hardware-aware, not wall-clock: HBM traffic counts the
-    posterior planes, factor/cost matrices, and per-task row reads once
-    each (the interval stacks are VMEM/cache-resident carries and never
-    round-trip), and the compute term counts the arithmetic of each
-    fused stage.  `device_time` is the perfect-overlap max of the two —
-    what the fused pipeline costs a device per replan, the number the
-    <1 ms fleet-scale target is stated against."""
-    n_tasks: int
-    n_nodes: int
-    dep_width: int
-    slots: int
-    flops: float
-    hbm_bytes: float
-
-    @property
-    def t_compute(self) -> float:
-        return self.flops / PEAK_FLOPS
-
-    @property
-    def t_memory(self) -> float:
-        return self.hbm_bytes / HBM_BW
-
-    @property
-    def bottleneck(self) -> str:
-        return "memory" if self.t_memory >= self.t_compute else "compute"
-
-    @property
-    def device_time(self) -> float:
-        return max(self.t_compute, self.t_memory)
-
-    def achieved_fraction(self, measured_seconds: float) -> float:
-        """Achieved-vs-peak: modeled device time over a measured time —
-        1.0 means the measurement hit the roofline."""
-        return self.device_time / max(measured_seconds, 1e-30)
-
-    def to_dict(self) -> dict:
-        return {
-            "n_tasks": self.n_tasks, "n_nodes": self.n_nodes,
-            "dep_width": self.dep_width, "slots": self.slots,
-            "flops": self.flops, "hbm_bytes": self.hbm_bytes,
-            "t_compute": self.t_compute, "t_memory": self.t_memory,
-            "bottleneck": self.bottleneck,
-            "device_time_model": self.device_time,
-        }
-
-
-def decision_plane_roofline(n_tasks: int, n_nodes: int, dep_width: int = 4,
-                            slots: int = 48, dtype_bytes: int = 4
-                            ) -> DecisionPlaneTerms:
-    """Analytic cost of one fused replan round at (T, N, D, S).
-
-    Stages (T=n_tasks, N=n_nodes, D=dep_width, S=slots, db=dtype_bytes):
-
-      predict+quantile  ~12 flops/task scalar predictive + 4 flops/cell
-                        scale + z-band; reads 11 posterior planes (T,)
-                        + the (T, N) factor matrix, writes (T, N) costs
-      upward rank       (T, N) mean reduce + 2 flops/edge recurrence;
-                        re-reads the cost matrix
-      EFT sweep         per task: (D, N) dep comm gather (2 flops/cell),
-                        (N, S) gap search (~6 flops/cell: shift, max,
-                        add, compare, select, min-reduce), S-wide
-                        insertion update; re-reads each task's cost row,
-                        writes 3 scalars/task.  Interval stacks are
-                        resident carries — no HBM round-trips.
-    """
-    T, N, D, S = n_tasks, n_nodes, dep_width, slots
-    db = float(dtype_bytes)
-    cells = T * N
-    flops = (12.0 * T + 4.0 * cells)                  # predict + quantile
-    flops += cells + 2.0 * T * D                      # rank
-    flops += T * (6.0 * N * S + 2.0 * D * N + 8.0 * S)  # sweep
-    hbm = 11.0 * T * db + cells * db + cells * db     # posts+factors, W out
-    hbm += cells * db + T * db                        # rank pass
-    hbm += cells * db + 3.0 * T * db                  # sweep row reads+outs
-    hbm += 2.0 * N * S * db                           # interval stack init
-    return DecisionPlaneTerms(n_tasks=T, n_nodes=N, dep_width=D, slots=S,
-                              flops=flops, hbm_bytes=hbm)

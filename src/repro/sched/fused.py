@@ -1,12 +1,20 @@
-"""Device-resident fused decision plane: predict -> quantile -> upward-rank
--> candidate-EFT sweep with persistent posterior rows, updated in place.
+"""The decision plane's HEFT engine and its resident prediction rows.
 
-The PR-4 decision plane already batches the prediction matrix into one
-dispatch per planning round, but every round still *re-materializes* it —
-a full store gather + predictive call + factor matrix — and then runs
-HEFT's ranking and placement through per-task Python/NumPy loops.  At
-fleet scale (thousands of tenant workflows replanning continuously) the
-decision plane itself is the hot path.  This module keeps it resident:
+  * `fused_heft_schedule` — the one HEFT entry point.  It takes a
+    `sched.plane.PredictionMatrix` (optionally scheduled at a quantile,
+    mean + z*std) or a scalar callable `(uid, node) -> seconds`, and is
+    bitwise equal to the plain reference `heft.heft_schedule_reference`
+    over the same costs.  The upward ranks reuse a per-(dag, cluster)
+    `_PlanContext` (comm structure, successor lists, the W-independent
+    avg-comm terms), so a warm replan pays only the O(T * N) w_avg cumsum,
+    the reverse-topo recurrence and the sweep.  The candidate-EFT sweep
+    is chosen by problem size: below `_JIT_MIN_CELLS` (task x node) cells
+    a float64 NumPy sweep over flat (N, S) busy-interval arrays (one
+    vectorized gap search over every node per task), at and above it
+    `kernels.decision_plane.eft_sweep`, the whole insertion loop as ONE
+    jitted float64 dispatch on `sweep_device()` (the host CPU).  Both are
+    bitwise the reference: the sweep holds no multi-term sums, so there
+    is nothing for a compiler to reassociate.
 
   * `FusedPlane` — holds one workflow's raw predictive rows (mean/std per
     task), the static factor matrix, and the streaming node corrections
@@ -17,32 +25,11 @@ decision plane itself is the hot path.  This module keeps it resident:
     them in place.  Because the predictive is elementwise per row, a
     dirty-subset update is bit-identical to a full re-gather.
 
-  * `fused_heft_schedule` — the fused scheduling engine.  Bit-identical
-    to `heft.heft_schedule_matrix` (the parity suite asserts equality on
-    random DAGs/clusters), but the candidate-EFT sweep runs on flat
-    (N, S) busy-interval arrays instead of per-node Python lists and slot
-    loops: per task, ONE vectorized gap search over every node replaces N
-    `_earliest_slot` calls.  The W-independent half of the upward rank
-    (the avg pairwise comm term, O(T * N^2)) is cached per (dag, cluster)
-    on the plane — it never changes between rounds, so a warm replan pays
-    only the O(T * N) w_avg cumsum, the reverse-topo recurrence, and the
-    sweep.
-
   * `replan_many` — megabatched replans across planes (tenants /
     workflows): the dirty rows of ALL planes are coalesced into ONE
-    padded predictive dispatch (`store.compute.predict_stacked`), the way
-    `fit_stacked` batches the fleet refresh, then each request is
-    scheduled off its resident rows.
-
-  * The candidate-EFT sweep itself has two engines: a float64 NumPy
-    engine (flat interval arrays, the portable fallback and parity
-    oracle) and the `kernels.decision_plane.eft_sweep` jitted engine —
-    the whole per-task insertion loop compiled into ONE dispatch (run in
-    float64 via jax's x64 mode on `sweep_device()`).  The jit
-    engine is an order of magnitude faster at fleet scale and remains
-    bit-identical: the sweep contains no multi-term sums, so there is
-    nothing for the compiler to reassociate.  `engine="auto"` picks by
-    problem size (the dispatch overhead dominates tiny DAGs).
+    padded predictive dispatch (`store.compute.predict_stacked`), then
+    the sweeps of same-cluster, same-shape requests at jit size run as
+    ONE vmapped dispatch (`kernels.decision_plane.eft_sweep_many`).
 
 Bit-parity notes (why the vectorized gap search is exact): the insertion
 policy keeps each node's busy intervals non-overlapping and sorted, so
@@ -65,7 +52,7 @@ and `lotaru.plane.predict_dispatches`, as in `PlaneStats`.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
@@ -86,9 +73,9 @@ __all__ = ["FusedPlane", "PlaneStats", "ReplanRequest",
 
 _NEG_INF = float("-inf")
 
-# auto engine policy: the jitted sweep is one compiled dispatch but pays
+# sweep selection: the jitted sweep is one compiled dispatch but pays
 # jit/dispatch overhead; below this many (task x node) cells the NumPy
-# engine wins and avoids compiles for throwaway shapes
+# sweep wins and avoids compiles for throwaway shapes
 _JIT_MIN_CELLS = 5000
 # task/dep dims are padded to bucket multiples so shrinking replan
 # frontiers (the rescheduler re-plans ever-smaller sub-DAGs) reuse one
@@ -249,7 +236,7 @@ def _ready_rows(ctx: _PlanContext, dag: WorkflowDAG, nodes: List[NodeSpec],
     """Materialize external ready-time constraints as a (T, N) array in
     topo-row order (None when unconstrained: the caller uses a shared
     zero matrix).  Callable form pays the same T x N calls the reference
-    engine would have made."""
+    would have made."""
     if ready_at is None:
         return None
     if isinstance(ready_at, np.ndarray):
@@ -267,30 +254,39 @@ def _ready_rows(ctx: _PlanContext, dag: WorkflowDAG, nodes: List[NodeSpec],
 
 
 def fused_heft_schedule(dag: WorkflowDAG, nodes: List[NodeSpec],
-                        matrix: PredictionMatrix,
+                        matrix: Union[PredictionMatrix,
+                                      Callable[[str, NodeSpec], float]],
                         ready_at=None,
                         node_available: Optional[Dict[str, float]] = None,
                         quantile: Optional[float] = None,
                         rank_cache: Optional[dict] = None,
-                        engine: str = "auto",
                         W: Optional[np.ndarray] = None,
                         dep_width: int = 0) -> Schedule:
-    """Fused-engine HEFT: bit-identical to `heft.heft_schedule_matrix`.
+    """HEFT over `matrix`, bitwise equal to `heft.heft_schedule_reference`.
 
-    `ready_at` additionally accepts a precomputed (T, N) array (rows in
-    `dag.topo_order()` order) so replans can charge external dependency
-    comm without T x N Python callbacks.  `rank_cache` is an optional
-    dict the caller keeps across rounds; per-(dag, cluster) invariants
-    (comm structure, successor lists, the W-independent avg-comm rank
-    terms, the sweep's static arrays) are memoized in it.  `engine`:
-    'numpy' = flat-array host sweep; 'jit' = one compiled dispatch
-    (`kernels.decision_plane.eft_sweep` in float64); 'auto' picks by
-    problem size.  `W` overrides the cost matrix (topo-row order) — the
-    resident plane passes its fused cost view so the matrix is never
-    re-derived here.  `dep_width`: the jit sweep's dependency columns are
-    at least this many (rounded up to a bucket); a caller that plans
-    sub-DAGs of one DAG passes that DAG's largest fan-in, so all of them
-    hit one compiled sweep per task bucket."""
+    `matrix` is a `PredictionMatrix` covering every task in `dag`, or a
+    scalar callable (uid, node) -> seconds, materialized once.  `ready_at`
+    constrains start times from outside the DAG (in-flight replans: data
+    from finished tasks): a {uid: time} dict, a callable (uid, node) ->
+    time, or a precomputed (T, N) array in `dag.topo_order()` rows, which
+    spares T x N Python callbacks.  `node_available` maps node name ->
+    earliest free time.  `quantile` schedules on mean + z*std; a callable
+    carries no uncertainty, so it rejects a quantile.  `rank_cache` is an
+    optional dict the caller keeps across rounds; per-(dag, cluster)
+    invariants (comm structure, successor lists, the W-independent
+    avg-comm rank terms, the sweep's static arrays) are memoized in it.
+    `W` overrides the cost matrix (topo-row order) — the resident plane
+    passes its fused cost view so the matrix is never re-derived here.
+    `dep_width`: the jit sweep's dependency columns are at least this
+    many (rounded up to a bucket); a caller that plans sub-DAGs of one
+    DAG passes that DAG's largest fan-in, so all of them hit one compiled
+    sweep per task bucket."""
+    if not isinstance(matrix, PredictionMatrix):
+        if quantile is not None:
+            raise ValueError("quantile scheduling needs a PredictionMatrix "
+                             "(a scalar callable carries no uncertainty)")
+        matrix = PredictionMatrix.from_callable(list(dag.tasks), nodes,
+                                                matrix)
     with obs.span("lotaru.sched.rank"):
         ctx = _context(dag, nodes, rank_cache, dep_width)
     if W is None:
@@ -301,8 +297,7 @@ def fused_heft_schedule(dag: WorkflowDAG, nodes: List[NodeSpec],
     if ready_at is not None and not isinstance(ready_at, np.ndarray):
         with obs.span("lotaru.sched.ready"):
             ready_at = _ready_rows(ctx, dag, nodes, ready_at)
-    if engine == "auto":
-        engine = "jit" if W.size >= _JIT_MIN_CELLS else "numpy"
+    engine = "jit" if W.size >= _JIT_MIN_CELLS else "numpy"
     with obs.span("lotaru.sched.sweep", engine=engine):
         if engine == "jit":
             return _schedule_jit(ctx, dag, nodes, W, rank, ready_at,
@@ -422,34 +417,38 @@ def _build_schedule(ctx: _PlanContext, order_arr: np.ndarray,
 def sweep_device():
     """The device the float64 jit sweep runs on: the process's CPU device,
     on every platform.  The sweep's contract is schedules bitwise equal to
-    `heft.heft_schedule_matrix`; a TPU emulates float64 and does not keep
-    it, so a process that holds a TPU places the sweep on its host CPU."""
+    `heft.heft_schedule_reference`; a TPU emulates float64 and does not
+    keep it, so a process that holds a TPU places the sweep on its host
+    CPU."""
     import jax
     return jax.devices("cpu")[0]
+
+
+def _run_sweep(ctxs: Sequence[_PlanContext], sweep) -> List[np.ndarray]:
+    """`sweep(S)` -> (assign, est, eft, cnt) as host arrays, run in float64
+    on `sweep_device()` with S the largest slot cap of `ctxs`.  When an
+    interval stack overflowed (the gap search needs >= 1 spare pad column
+    per node) every context's cap doubles and the sweep recompiles."""
+    import jax
+    while True:
+        S = max(c.slot_cap for c in ctxs)
+        with jax.enable_x64(True), jax.default_device(sweep_device()):
+            out = [np.asarray(a) for a in sweep(S)]
+        if out[3].max() <= S - 1:
+            return out
+        for c in ctxs:
+            c.slot_cap = max(c.slot_cap, S * 2)
 
 
 def _schedule_jit(ctx: _PlanContext, dag: WorkflowDAG,
                   nodes: List[NodeSpec], W: np.ndarray,
                   rank: Dict[str, float], ready_at,
                   node_available: Optional[Dict[str, float]]) -> Schedule:
-    import jax
-
     from repro.kernels import decision_plane as dp
     packed = _sweep_inputs(ctx, dag, nodes, W, rank, ready_at,
                            node_available)
-    while True:
-        S = ctx.slot_cap
-        with jax.enable_x64(True), jax.default_device(sweep_device()):
-            assign, est, eft, cnt = dp.eft_sweep(
-                *packed, ctx.same, ctx.gbps_min, S=S)
-            assign = np.asarray(assign)
-            est = np.asarray(est)
-            eft = np.asarray(eft)
-            cnt = np.asarray(cnt)
-        if cnt.max() <= S - 1:
-            break
-        ctx.slot_cap = S * 2      # interval stacks overflowed: the gap
-        # search needs >= 1 spare pad column per node — recompile larger
+    assign, est, eft, _ = _run_sweep(
+        [ctx], lambda S: dp.eft_sweep(*packed, ctx.same, ctx.gbps_min, S=S))
     return _build_schedule(ctx, packed[1], assign, est, eft)
 
 
@@ -485,7 +484,7 @@ class FusedPlane:
 
     def __init__(self, service, nodes: Sequence[NodeSpec],
                  entries: Optional[Sequence[Tuple[str, str, float]]] = None,
-                 dag: Optional[WorkflowDAG] = None, impl: str = "auto"):
+                 dag: Optional[WorkflowDAG] = None):
         if entries is None:
             if dag is None:
                 raise ValueError("FusedPlane needs `entries` or a `dag`")
@@ -494,7 +493,6 @@ class FusedPlane:
         self.service = service
         self.nodes = list(nodes)
         self.node_names = [n.name for n in self.nodes]
-        self.impl = impl
         self.entries = [(u, t, float(gb)) for u, t, gb in entries]
         self.uids: Tuple[str, ...] = tuple(u for u, _, _ in self.entries)
         self._tasks = [t for _, t, _ in self.entries]
@@ -565,8 +563,7 @@ class FusedPlane:
             snap, idx = self.collect_dirty()
             if len(idx):
                 post = snap.gather([self._keys[i] for i in idx])
-                mean, std = compute.predict_stacked(self._x[idx], post,
-                                                    impl=self.impl)
+                mean, std = compute.predict_stacked(self._x[idx], post)
                 self.stats.predict_dispatches += 1
                 obs.count("lotaru.plane.predict_dispatches", 1)
                 self.apply_rows(snap, idx, mean, std)
@@ -639,16 +636,14 @@ class FusedPlane:
     # ---- scheduling --------------------------------------------------------
     def schedule(self, dag: WorkflowDAG, ready_at=None,
                  node_available: Optional[Dict[str, float]] = None,
-                 quantile: Optional[float] = None,
-                 engine: str = "auto") -> Schedule:
+                 quantile: Optional[float] = None) -> Schedule:
         """One fused replan round off the resident rows + cost view."""
         mat, W = self.cost_view(dag, quantile)
         return fused_heft_schedule(dag, self.nodes, mat,
                                    ready_at=ready_at,
                                    node_available=node_available,
                                    quantile=quantile,
-                                   rank_cache=self.rank_cache,
-                                   engine=engine, W=W)
+                                   rank_cache=self.rank_cache, W=W)
 
 
 # ---------------------------------------------------------------------------
@@ -665,16 +660,13 @@ class ReplanRequest:
     quantile: Optional[float] = None
 
 
-def replan_many(requests: Sequence[ReplanRequest],
-                impl: str = "auto", fuse_sweeps: bool = True
-                ) -> List[Schedule]:
+def replan_many(requests: Sequence[ReplanRequest]) -> List[Schedule]:
     """Megabatched replans across tenants/workflows: the dirty rows of
     every plane are coalesced into ONE padded predictive dispatch (the
     way `fit_stacked` batches the fleet refresh), scattered back into
     each plane's resident stack, then the EFT sweeps of requests sharing
     one cluster and padded shape run as ONE vmapped dispatch
-    (`kernels.decision_plane.eft_sweep_many`; `fuse_sweeps=False` falls
-    back to per-request scheduling).  Bit-identical to calling
+    (`kernels.decision_plane.eft_sweep_many`).  Bit-identical to calling
     `plane.schedule(...)` per request — the predictive is elementwise and
     the vmapped sweep runs each lane's exact scalar program, so batching
     changes nothing but the dispatch count."""
@@ -697,8 +689,7 @@ def replan_many(requests: Sequence[ReplanRequest],
         x_all = np.concatenate(xs)
         post_all = {leaf: np.concatenate([p[leaf] for p in posts])
                     for leaf in compute.LEAVES}
-        mean_all, std_all = compute.predict_stacked(x_all, post_all,
-                                                    impl=impl)
+        mean_all, std_all = compute.predict_stacked(x_all, post_all)
         off = 0
         for req, snap, idx in collected:
             if len(idx):
@@ -713,26 +704,27 @@ def replan_many(requests: Sequence[ReplanRequest],
     else:
         for req, snap, idx in collected:
             req.plane.apply_rows(snap, idx, np.empty(0), np.empty(0))
-    return _schedule_requests(requests, fuse_sweeps)
+    return _schedule_requests(requests)
 
 
-def _schedule_requests(requests: Sequence[ReplanRequest],
-                       fuse_sweeps: bool) -> List[Schedule]:
+def _schedule_requests(requests: Sequence[ReplanRequest]
+                       ) -> List[Schedule]:
     """Schedule every (synced) request, vmapping the EFT sweeps of
-    same-cluster, same-padded-shape groups into one dispatch each."""
+    same-cluster, same-padded-shape groups at jit size into one dispatch
+    each; smaller requests take `fused_heft_schedule`'s NumPy sweep."""
     results: List[Optional[Schedule]] = [None] * len(requests)
     groups: Dict[tuple, list] = {}
     for pos, req in enumerate(requests):
         plane = req.plane
         mat, W = plane.cost_view(req.dag, req.quantile)
-        ctx = _context(req.dag, plane.nodes, plane.rank_cache)
-        rank = ctx.ranks(req.dag, W)
-        if not (fuse_sweeps and W.size >= _JIT_MIN_CELLS):
+        if W.size < _JIT_MIN_CELLS:
             results[pos] = fused_heft_schedule(
                 req.dag, plane.nodes, mat, ready_at=req.ready_at,
                 node_available=req.node_available, quantile=req.quantile,
                 rank_cache=plane.rank_cache, W=W)
             continue
+        ctx = _context(req.dag, plane.nodes, plane.rank_cache)
+        rank = ctx.ranks(req.dag, W)
         packed = _sweep_inputs(ctx, req.dag, plane.nodes, W, rank,
                                req.ready_at, req.node_available)
         # one group = one cluster comm structure + one padded shape: the
@@ -748,31 +740,19 @@ def _schedule_requests(requests: Sequence[ReplanRequest],
 
 def _dispatch_group(members: list, results: List[Optional[Schedule]]
                     ) -> None:
-    import jax
-
     from repro.kernels import decision_plane as dp
-    ctx0 = members[0][2]
-    stacked = [np.stack([m[3][k] for m in members])
-               for k in range(6)]
-    while True:
-        S = max(m[2].slot_cap for m in members)
-        with jax.enable_x64(True), jax.default_device(sweep_device()):
-            if len(members) == 1:
-                assign, est, eft, cnt = dp.eft_sweep(
-                    *members[0][3], ctx0.same, ctx0.gbps_min, S=S)
-                assign, est, eft = assign[None], est[None], eft[None]
-                cnt = np.asarray(cnt)[None]
-            else:
-                assign, est, eft, cnt = dp.eft_sweep_many(
-                    *stacked, ctx0.same, ctx0.gbps_min, S=S)
-            assign = np.asarray(assign)
-            est = np.asarray(est)
-            eft = np.asarray(eft)
-            cnt = np.asarray(cnt)
-        if cnt.max() <= S - 1:
-            break
-        for _, _, ctx, _ in members:
-            ctx.slot_cap = max(ctx.slot_cap, S * 2)
+    ctxs = [m[2] for m in members]
+    same, gbps_min = ctxs[0].same, ctxs[0].gbps_min
+    if len(members) == 1:
+        packed = members[0][3]
+        out = _run_sweep(ctxs, lambda S: dp.eft_sweep(*packed, same,
+                                                      gbps_min, S=S))
+        assign, est, eft = (a[None] for a in out[:3])
+    else:
+        stacked = [np.stack([m[3][k] for m in members]) for k in range(6)]
+        assign, est, eft, _ = _run_sweep(
+            ctxs, lambda S: dp.eft_sweep_many(*stacked, same, gbps_min,
+                                              S=S))
     for b, (pos, req, ctx, packed) in enumerate(members):
         req.plane.stats.sweep_dispatches += 1
         results[pos] = _build_schedule(ctx, packed[1], assign[b],

@@ -10,8 +10,8 @@ dispatch.  `PredictionMatrix` materializes that matrix once
 (tasks x nodes mean/std arrays plus uid/node index maps) and the policy
 modules consume rows of it:
 
-  * `heft_schedule_matrix` ranks and places straight off the arrays
-    (optionally at a pessimistic quantile, mean + z*std);
+  * `sched.fused.fused_heft_schedule` ranks and places straight off the
+    arrays (optionally at a pessimistic quantile, mean + z*std);
   * `sched.straggler.decide_speculation` reads a `TaskDistribution` row;
   * `sched.cost.predicted_cost_quantile` bills quantile durations;
   * `sched.carbon.shift_workload` books quantile hours from a
@@ -19,7 +19,7 @@ modules consume rows of it:
 
 Builders: `from_service` costs ONE store gather + ONE batched predictive
 dispatch (`PredictionService.predict_matrix`); `from_callable` adapts any
-scalar `predict(uid, node)` so legacy callers keep working bit-identically.
+scalar `predict(uid, node)` into the same matrix.
 """
 from __future__ import annotations
 
@@ -157,9 +157,9 @@ class PredictionMatrix:
                       predict: Callable[[str, NodeSpec], float]
                       ) -> "PredictionMatrix":
         """Adapt a scalar predict(uid, node) callback (stds are zero: a
-        bare callable carries no uncertainty).  This is the compatibility
-        shim `heft_schedule` uses, so legacy callers pay the same O(T x N)
-        calls they always did — once — and then run the vectorized core."""
+        bare callable carries no uncertainty).  `fused_heft_schedule`
+        wraps a callable with it, so a scalar caller pays its O(T x N)
+        calls once and then runs the vectorized engine."""
         means = np.asarray([[float(predict(u, n)) for n in nodes]
                             for u in uids], np.float64)
         return cls(list(uids), [n.name for n in nodes], means)

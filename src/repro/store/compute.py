@@ -69,23 +69,6 @@ def fit_stacked(x: np.ndarray, y: np.ndarray, mask: np.ndarray,
     return {k: np.asarray(v, np.float64) for k, v in post.items()}
 
 
-def fold_stacked(nigs, xs, ys, impl: str = "auto"):
-    """Batched streaming-observation fold — the ingest-side sibling of
-    `fit_stacked`: T NIG states + ragged per-task observation rows ->
-    T updated states from ONE fold dispatch (`core.bayes.nig_update_batch`).
-
-    Unlike its read-path siblings, impl='auto' NEVER routes to a device
-    kernel — not even on TPU: the ingest plane's exactness contract
-    (bit-identical to the scalar `nig_update` chain, which feeds state
-    digests and failover replay) only holds for the float64 CPU fold.
-    The float32 'pallas'/'interpret'/'scan' forms are an explicit opt-in
-    for device-resident posterior banks that keep no digest."""
-    from repro.core import bayes
-    if impl in ("pallas", "interpret", "scan"):
-        return bayes.nig_update_batch(nigs, xs, ys, impl=impl)
-    return bayes.nig_update_batch(nigs, xs, ys, impl="numpy")
-
-
 def scale(mean: np.ndarray, std: np.ndarray, factors: np.ndarray
           ) -> Tuple[np.ndarray, np.ndarray]:
     """Extrapolation-factor rescaling (with the mean floor) shared by the

@@ -1,6 +1,7 @@
-"""Vectorized decision plane: bit-parity of matrix HEFT vs the scalar
-reference, quantile-aware scheduling, one-dispatch prediction matrices,
-the shared AS 241 inverse-normal, and speculative re-execution."""
+"""Vectorized decision plane: bit-parity of the fused HEFT engine over a
+scalar callable vs the scalar reference, quantile-aware scheduling,
+one-dispatch prediction matrices, the shared AS 241 inverse-normal, and
+speculative re-execution."""
 import math
 
 import numpy as np
@@ -15,12 +16,12 @@ from repro.online import (OnlinePredictor, OnlineReschedulingPlanner,
 from repro.online.events import PredictionQuery
 from repro.sched.cluster import LOCAL, TARGET_MACHINES
 from repro.sched.cost import predicted_cost, predicted_cost_quantile
-from repro.sched.heft import (heft_schedule, heft_schedule_matrix,
-                              heft_schedule_reference)
+from repro.sched.fused import fused_heft_schedule
+from repro.sched.heft import heft_schedule_reference
 from repro.sched.plane import PredictionMatrix, RuntimeDist, quantile_z
 from repro.sched.straggler import ndtri
 from repro.workflow.dag import TaskInstance, WorkflowDAG
-from repro.workflow.generator import GroundTruth, build_workflow
+from repro.workflow.generator import WORKFLOWS, GroundTruth, build_workflow
 from repro.workflow.profiling import local_profiling
 from repro.workflow.simulator import (SpeculationPolicy, execute_adaptive,
                                       execute_schedule, random_cluster)
@@ -105,7 +106,7 @@ def test_matrix_heft_bit_parity_random_dags(seed):
     costs = {(u, n.name): float(rng.uniform(1.0, 500.0))
              for u in dag.tasks for n in nodes}
     predict = lambda u, n: costs[(u, n.name)]
-    _assert_bit_identical(heft_schedule(dag, nodes, predict),
+    _assert_bit_identical(fused_heft_schedule(dag, nodes, predict),
                           heft_schedule_reference(dag, nodes, predict))
 
 
@@ -123,19 +124,20 @@ def test_matrix_heft_bit_parity_with_replan_constraints(seed):
     ready = {u: float(rng.uniform(0.0, 50.0)) for u in dag.tasks}
     avail = {n.name: float(rng.uniform(0.0, 80.0)) for n in nodes}
     _assert_bit_identical(
-        heft_schedule(dag, nodes, predict, ready_at=ready,
+        fused_heft_schedule(dag, nodes, predict, ready_at=ready,
                       node_available=avail),
         heft_schedule_reference(dag, nodes, predict, ready_at=ready,
                                 node_available=avail))
 
 
-def test_matrix_heft_bit_parity_real_workflow():
-    dag = build_workflow("eager", seed=0)
-    gt = GroundTruth("eager", seed=0)
+@pytest.mark.parametrize("workflow", WORKFLOWS)
+def test_matrix_heft_bit_parity_real_workflow(workflow):
+    dag = build_workflow(workflow, seed=0)
+    gt = GroundTruth(workflow, seed=0)
     nodes = list(TARGET_MACHINES)
     predict = lambda u, n: gt.runtime(dag.tasks[u].task_name,
                                       dag.tasks[u].input_gb, n, u)
-    _assert_bit_identical(heft_schedule(dag, nodes, predict),
+    _assert_bit_identical(fused_heft_schedule(dag, nodes, predict),
                           heft_schedule_reference(dag, nodes, predict))
 
 
@@ -144,7 +146,7 @@ def test_quantile_requires_uncertainty():
     dag = build_workflow("bacass", seed=0)
     nodes = list(TARGET_MACHINES)
     with pytest.raises(ValueError, match="quantile"):
-        heft_schedule(dag, nodes, lambda u, n: 1.0, quantile=0.95)
+        fused_heft_schedule(dag, nodes, lambda u, n: 1.0, quantile=0.95)
 
 
 def test_quantile_scheduling_prefers_certain_node():
@@ -157,13 +159,13 @@ def test_quantile_scheduling_prefers_certain_node():
     means = np.asarray([[100.0, 101.0]])
     stds = np.asarray([[50.0, 0.1]])
     mat = PredictionMatrix(["a"], [n.name for n in nodes], means, stds)
-    mean_sched = heft_schedule_matrix(dag, nodes, mat)
+    mean_sched = fused_heft_schedule(dag, nodes, mat)
     assert mean_sched.assignment["a"] == "A1"           # 100 < 101
-    q95 = heft_schedule_matrix(dag, nodes, mat, quantile=0.95)
+    q95 = fused_heft_schedule(dag, nodes, mat, quantile=0.95)
     assert q95.assignment["a"] == "A2"   # 100+1.645*50 >> 101+1.645*0.1
     # q=0.5 is exactly the mean schedule (z(0.5) == 0)
     _assert_bit_identical(mean_sched,
-                          heft_schedule_matrix(dag, nodes, mat, quantile=0.5))
+                          fused_heft_schedule(dag, nodes, mat, quantile=0.5))
 
 
 # --- one-dispatch prediction matrix ---------------------------------------------
@@ -223,7 +225,7 @@ def test_cost_quantile_bounds_mean_cost():
     mat = PredictionMatrix.from_callable(list(dag.tasks), nodes, predict)
     mat = PredictionMatrix(mat.uids, mat.node_names, mat.means,
                            0.2 * mat.means)        # 20% predictive std
-    sched = heft_schedule_matrix(dag, nodes, mat)
+    sched = fused_heft_schedule(dag, nodes, mat)
     base = predicted_cost(sched, nodes, "minute")
     assert predicted_cost_quantile(sched, mat, nodes, "minute", q=0.5) \
         == pytest.approx(base, rel=1e-9)
@@ -312,7 +314,7 @@ def test_static_execution_unaffected_by_speculation_plumbing():
     nodes = list(TARGET_MACHINES)
     true_rt = lambda u, n: gt.runtime(dag.tasks[u].task_name,
                                       dag.tasks[u].input_gb, n, u)
-    sched = heft_schedule(dag, nodes, true_rt)
+    sched = fused_heft_schedule(dag, nodes, true_rt)
     res = execute_schedule(dag, sched, nodes, true_rt)
     assert res.n_backups == 0 and res.backup_waste_s == 0.0
     assert len(res.records) == len(dag.tasks)

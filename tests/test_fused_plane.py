@@ -1,8 +1,10 @@
-"""Fused decision plane: bit-parity of both sweep engines (NumPy and the
-jitted `kernels.decision_plane` dispatch) vs `heft_schedule_matrix`,
-dirty-row residency vs full re-gathers, megabatched replans (one
-predictive dispatch + one vmapped sweep per cluster group), the Pallas
-kernel forms in interpret mode, and the decision-plane roofline model."""
+"""Fused decision plane: bit-parity of both sweeps (NumPy and the jitted
+`kernels.decision_plane` dispatch) vs the scalar `heft_schedule_reference`,
+dirty-row residency vs full re-gathers, and megabatched replans (one
+predictive dispatch + one vmapped sweep per cluster group)."""
+import contextlib
+import math
+
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -16,7 +18,7 @@ from repro.sched import fused as fused_mod
 from repro.sched.cluster import LOCAL, TARGET_MACHINES
 from repro.sched.fused import (FusedPlane, ReplanRequest,
                                fused_heft_schedule, replan_many)
-from repro.sched.heft import heft_schedule_matrix, upward_ranks
+from repro.sched.heft import heft_schedule_reference
 from repro.sched.plane import PredictionMatrix
 from repro.store import compute
 from repro.store.posterior import PosteriorStore
@@ -66,26 +68,51 @@ def _same_schedule(a, b):
     assert a.est == b.est
 
 
+def _reference(dag, nodes, mat, quantile=None, **kw):
+    """`heft_schedule_reference` through a callable over the cost matrix
+    `mat` gives at `quantile` — the costs the fused engine schedules on."""
+    uids = list(dag.tasks)
+    W = mat.costs(uids, [n.name for n in nodes], quantile=quantile)
+    row = {u: i for i, u in enumerate(uids)}
+    col = {n.name: j for j, n in enumerate(nodes)}
+    return heft_schedule_reference(
+        dag, nodes, lambda u, n: float(W[row[u], col[n.name]]), **kw)
+
+
+@contextlib.contextmanager
+def _engine(name):
+    """Hold `fused_heft_schedule` to one sweep at every size: 'jit' or
+    'numpy'.  A context, not a fixture: hypothesis refuses
+    function-scoped fixtures."""
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(fused_mod, "_JIT_MIN_CELLS",
+                   0 if name == "jit" else math.inf)
+        yield
+
+
 # --- engine parity ---------------------------------------------------------------
 
+@pytest.mark.parametrize("engine", ["numpy", "jit"])
 @settings(max_examples=6, deadline=None)
 @given(seed=st.integers(0, 10 ** 6), n_tasks=st.integers(5, 40),
        n_nodes=st.integers(4, 6))
-def test_fused_engines_bitwise_match_reference(seed, n_tasks, n_nodes):
+def test_fused_engines_bitwise_match_reference(engine, seed, n_tasks,
+                                               n_nodes):
     dag, nodes, svc = _build(n_tasks, n_nodes, seed)
     mat = _matrix(dag, nodes, svc)
     cache = {}
     for q in (None, 0.5, 0.95):
-        want = heft_schedule_matrix(dag, nodes, mat, quantile=q)
-        for engine in ("numpy", "jit"):
+        want = _reference(dag, nodes, mat, quantile=q)
+        with _engine(engine):
             got = fused_heft_schedule(dag, nodes, mat, quantile=q,
-                                      rank_cache=cache, engine=engine)
-            _same_schedule(got, want)
+                                      rank_cache=cache)
+        _same_schedule(got, want)
 
 
+@pytest.mark.parametrize("engine", ["numpy", "jit"])
 @settings(max_examples=4, deadline=None)
 @given(seed=st.integers(0, 10 ** 6))
-def test_fused_engines_match_on_constrained_replans(seed):
+def test_fused_engines_match_on_constrained_replans(engine, seed):
     """node_available busy prefixes + external ready times (dict,
     callable, and precomputed-array forms) — the shapes `_replan` uses."""
     rng = np.random.default_rng(seed)
@@ -103,13 +130,12 @@ def test_fused_engines_match_on_constrained_replans(seed):
         # the reference takes dict/callable only; the (T, N) array form is
         # the fused engine's extension, built here from the same callable
         ref_ready = ready_fn if isinstance(ready, np.ndarray) else ready
-        want = heft_schedule_matrix(dag, nodes, mat, quantile=0.95,
-                                    ready_at=ref_ready, node_available=avail)
-        for engine in ("numpy", "jit"):
+        want = _reference(dag, nodes, mat, quantile=0.95,
+                          ready_at=ref_ready, node_available=avail)
+        with _engine(engine):
             got = fused_heft_schedule(dag, nodes, mat, quantile=0.95,
-                                      ready_at=ready, node_available=avail,
-                                      engine=engine)
-            _same_schedule(got, want)
+                                      ready_at=ready, node_available=avail)
+        _same_schedule(got, want)
 
 
 def test_auto_engine_policy_is_size_based(monkeypatch):
@@ -172,45 +198,14 @@ def test_frontiers_of_a_run_share_one_dep_width(seed):
                 tuple(sub.tasks), mat.node_names,
                 mat.means[[mat.uid_index[u] for u in sub.tasks]],
                 mat.stds[[mat.uid_index[u] for u in sub.tasks]])
-            want = heft_schedule_matrix(sub, nodes, sub_mat, quantile=0.95)
-            got = fused_heft_schedule(sub, nodes, sub_mat, quantile=0.95,
-                                      engine="jit", dep_width=fan_in + 5)
+            want = _reference(sub, nodes, sub_mat, quantile=0.95)
+            with _engine("jit"):
+                got = fused_heft_schedule(sub, nodes, sub_mat,
+                                          quantile=0.95,
+                                          dep_width=fan_in + 5)
             _same_schedule(got, want)
     assert widths == {bucket}
     assert max(own) <= bucket
-
-
-def test_upward_rank_kernel_matches_host_recurrence():
-    import jax
-
-    from repro.kernels import decision_plane as dp
-    from repro.sched.heft import comm_structure
-    dag, nodes, svc = _build(30, 4, 11)
-    mat = _matrix(dag, nodes, svc)
-    order = dag.topo_order()
-    names = [n.name for n in nodes]
-    W = mat.costs(order, names, quantile=0.5)
-    same, gbps_min = comm_structure(nodes)
-    want = upward_ranks(dag, nodes, W, order, same, gbps_min)
-
-    row_of = {u: i for i, u in enumerate(order)}
-    succ = dag.successors()
-    width = max(max((len(v) for v in succ.values()), default=1), 1)
-    succ_pad = np.full((len(order), width), -1, np.int32)
-    for i, u in enumerate(order):
-        for k, v in enumerate(succ[u]):
-            succ_pad[i, k] = row_of[v]
-    n_nodes = len(nodes)
-    avg_comm = np.asarray(
-        [float(np.where(same, 0.0,
-                        (dag.tasks[u].output_gb * 8.0)
-                        / gbps_min).ravel().cumsum()[-1]) / n_nodes ** 2
-         for u in order])
-    w_avg = W.cumsum(axis=1)[:, -1] / n_nodes
-    with jax.enable_x64(True):
-        got = np.asarray(dp.upward_rank(w_avg, avg_comm, succ_pad))
-    want_arr = np.asarray([want[u] for u in order])
-    assert np.array_equal(got, want_arr)
 
 
 # --- residency: dirty rows vs full re-gather -------------------------------------
@@ -237,7 +232,7 @@ def test_dirty_row_update_matches_full_regather():
         assert np.array_equal(mat.means, fresh.means)
         assert np.array_equal(mat.stds, fresh.stds)
         got = plane.schedule(dag, quantile=0.95)
-        want = heft_schedule_matrix(dag, nodes, fresh, quantile=0.95)
+        want = _reference(dag, nodes, fresh, quantile=0.95)
         _same_schedule(got, want)
     # residency did real work: one full gather, then dirty subsets only
     assert plane.stats.full_gathers == 1
@@ -275,10 +270,10 @@ def test_replan_many_single_predict_dispatch(monkeypatch):
                                         quantile=0.95)])
     assert len(calls) == 1            # both planes' rows in ONE dispatch
     mats = [_matrix(dag, nodes, svc), _matrix(dag2, nodes, svc)]
-    _same_schedule(scheds[0], heft_schedule_matrix(dag, nodes, mats[0],
-                                                   quantile=0.95))
-    _same_schedule(scheds[1], heft_schedule_matrix(dag2, nodes, mats[1],
-                                                   quantile=0.95))
+    _same_schedule(scheds[0], _reference(dag, nodes, mats[0],
+                                         quantile=0.95))
+    _same_schedule(scheds[1], _reference(dag2, nodes, mats[1],
+                                         quantile=0.95))
 
 
 def test_replan_many_fuses_same_cluster_sweeps(monkeypatch):
@@ -296,7 +291,7 @@ def test_replan_many_fuses_same_cluster_sweeps(monkeypatch):
     assert len(calls) == 1            # three tenants, one vmapped sweep
     mat = _matrix(dag, nodes, svc)
     for s, q in zip(scheds, (None, 0.5, 0.95)):
-        _same_schedule(s, heft_schedule_matrix(dag, nodes, mat, quantile=q))
+        _same_schedule(s, _reference(dag, nodes, mat, quantile=q))
 
 
 def test_eft_sweep_many_lanes_match_single():
@@ -320,74 +315,6 @@ def test_eft_sweep_many_lanes_match_single():
             single = dp.eft_sweep(*p, ctx.same, ctx.gbps_min, S=16)
             for lane, one in zip(many, single):
                 assert np.array_equal(lane[b], np.asarray(one))
-
-
-# --- Pallas kernel forms (interpret mode) ----------------------------------------
-
-def _dyadic_post(T, rng):
-    """Posterior rows with dyadic-rational leaves, exact in float32."""
-    def d(lo, hi):
-        return rng.integers(lo, hi, size=T) / 16.0
-    mu = np.stack([d(1, 32), d(1, 16)], axis=1)
-    sigma = np.zeros((T, 2, 2))
-    sigma[:, 0, 0] = d(1, 8)
-    sigma[:, 1, 1] = d(1, 8)
-    sigma[:, 0, 1] = sigma[:, 1, 0] = d(0, 4)
-    return {"mu": mu, "sigma": sigma, "beta_prec": 1.0 + d(1, 8),
-            "x_mu": d(0, 8), "x_sd": 1.0 + d(0, 8),
-            "y_mu": d(0, 8), "y_sd": 1.0 + d(0, 8)}
-
-
-def test_fused_cost_pallas_interpret_matches_ref():
-    import jax.numpy as jnp
-
-    from repro.kernels import decision_plane as dp
-    rng = np.random.default_rng(23)
-    T, N = 12, 8
-    x = jnp.asarray(rng.integers(1, 64, size=T) / 16.0, jnp.float32)
-    post = {k: jnp.asarray(v, jnp.float32)
-            for k, v in _dyadic_post(T, rng).items()}
-    factors = jnp.asarray(rng.integers(1, 32, size=(T, N)) / 8.0,
-                          jnp.float32)
-    for z in (0.0, 1.5):
-        want = np.asarray(dp.fused_cost_ref(x, post, factors, z))
-        got = np.asarray(dp.fused_cost(x, post, factors, z=z,
-                                       interpret=True))
-        np.testing.assert_allclose(got, want, rtol=1e-6, atol=0.0)
-
-
-def test_eft_sweep_pallas_interpret_matches_jit_float32():
-    from repro.kernels import decision_plane as dp
-    dag, nodes, svc = _build(16, 4, 29)
-    mat = _matrix(dag, nodes, svc)
-    ctx = fused_mod._PlanContext(dag, nodes)
-    W = mat.costs(ctx.order, ctx.names, quantile=0.5)
-    rank = ctx.ranks(dag, W)
-    pack = fused_mod._sweep_inputs(ctx, dag, nodes, W, rank, None, None)
-    f32 = [np.asarray(a, np.float32 if a.dtype.kind == "f" else a.dtype)
-           for a in pack]
-    want = dp.eft_sweep(*f32, ctx.same.astype(np.float32),
-                        np.asarray(ctx.gbps_min, np.float32), S=16)
-    got = dp.eft_sweep_pallas(*f32, ctx.same.astype(np.float32),
-                              np.asarray(ctx.gbps_min, np.float32),
-                              S=16, interpret=True)
-    n = len(ctx.order)      # padded (masked) rows are don't-care outputs
-    for g, w in zip(got[:3], want[:3]):
-        assert np.array_equal(np.asarray(g)[:n], np.asarray(w)[:n])
-
-
-# --- roofline --------------------------------------------------------------------
-
-def test_decision_plane_roofline_model():
-    from repro.perf.roofline import decision_plane_roofline
-    t = decision_plane_roofline(1000, 100, dep_width=10)
-    d = t.to_dict()
-    assert d["bottleneck"] in ("compute", "memory")
-    assert 0.0 < d["device_time_model"] < 1e-3    # fleet replan target
-    assert t.achieved_fraction(d["device_time_model"]) == pytest.approx(1.0)
-    # scaling sanity: 10x the work costs more on both axes
-    big = decision_plane_roofline(10000, 100, dep_width=10)
-    assert big.flops > t.flops and big.hbm_bytes > t.hbm_bytes
 
 
 # --- rescheduler residency -------------------------------------------------------

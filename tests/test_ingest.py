@@ -2,8 +2,8 @@
 exact replay of the scalar one.
 
 Covers the whole stack: `bayes.nig_update_batch` bit-parity vs the
-chained scalar `nig_update` (float64 oracle) and kernel-tolerance parity
-for the jax forms; `OnlinePredictor.observe_many` digest/prediction
+chained scalar `nig_update` (float64 oracle), with no form beside the
+float64 ones; `OnlinePredictor.observe_many` digest/prediction
 equivalence with the scalar observe chain under adversarial streams
 (unknown tasks, remote + unknown nodes, interleaved predicts);
 `OpLog.append_many` group commit (one frame + one flush, dense acks,
@@ -93,24 +93,6 @@ def test_nig_update_batch_bitwise_matches_scalar_chain(seed, t, kmax):
     for nig, xrow, g in zip(nigs, xs, got):
         assert g is not nig
         assert nig["n_obs"] == g["n_obs"] - len(xrow)
-
-
-def test_nig_update_batch_jax_forms_within_kernel_tolerance():
-    rng = np.random.default_rng(7)
-    nigs = _fresh_nigs(rng, 6)
-    xs = [list(rng.uniform(0.05, 3.0, 5)) for _ in nigs]
-    ys = [[float(rng.uniform(4.0, 120.0)) for _ in row] for row in xs]
-    exact = bayes.nig_update_batch(nigs, xs, ys)
-    for impl in ("scan", "interpret"):
-        loose = bayes.nig_update_batch(nigs, xs, ys, impl=impl)
-        for e, l in zip(exact, loose):
-            for key in ("mu", "b"):
-                np.testing.assert_allclose(
-                    np.asarray(l[key], np.float64), np.asarray(e[key]),
-                    rtol=2e-3, atol=2e-3,
-                    err_msg=f"{impl}: leaf {key!r} outside f32 tolerance")
-            # counters are exact (closed-form, host-side)
-            assert l["a"] == e["a"] and l["n_obs"] == e["n_obs"]
 
 
 def test_nig_update_batch_validates_ragged_rows():
@@ -568,16 +550,15 @@ def test_health_surfaces_and_clears_ingest_publish_failure(tmp_path):
 
 
 def test_fold_stacked_auto_stays_on_float64_chain():
-    """`fold_stacked` feeds digest-bearing streaming states, so its
-    default impl must be bitwise the scalar `nig_update` chain on EVERY
-    backend — the device kernels are explicit opt-ins only."""
-    from repro.store.compute import fold_stacked
+    """The ingest fold feeds digest-bearing streaming states, so its
+    default is bitwise the scalar `nig_update` chain on EVERY backend,
+    and it has no float32 form to opt into: a device impl name raises."""
     rng = np.random.default_rng(11)
     nigs = _fresh_nigs(rng, 5)
     xs = [list(rng.uniform(0.05, 3.0, int(rng.integers(0, 5))))
           for _ in nigs]
     ys = [[float(rng.uniform(4.0, 120.0)) for _ in row] for row in xs]
-    got = fold_stacked(nigs, xs, ys)
+    got = bayes.nig_update_batch(nigs, xs, ys)
     for nig, xr, yr, g in zip(nigs, xs, ys, got):
         want = dict(nig)
         for x, y in zip(xr, yr):
@@ -585,7 +566,12 @@ def test_fold_stacked_auto_stays_on_float64_chain():
         for key in ("mu", "v", "prec", "a", "b", "n_obs"):
             np.testing.assert_array_equal(
                 np.asarray(g[key]), np.asarray(want[key]),
-                err_msg=f"fold_stacked default diverges on leaf {key!r}")
+                err_msg=f"the default fold diverges on leaf {key!r}")
+    xs[0] = xs[0] or [1.0]
+    ys[0] = ys[0] or [2.0]
+    for impl in ("auto", "scan", "pallas", "interpret"):
+        with pytest.raises(ValueError, match="unknown impl"):
+            bayes.nig_update_batch(nigs, xs, ys, impl=impl)
 
 
 # --- fused decision plane: batch-dirty rows in one pass ------------------------

@@ -11,7 +11,7 @@ from repro.online import (OnlinePredictor, OnlineReschedulingPlanner,
                           PredictionService, TaskCompletion)
 from repro.online.events import PredictionQuery
 from repro.sched.cluster import LOCAL, PAPER_MACHINES, TARGET_MACHINES
-from repro.sched.heft import heft_schedule
+from repro.sched.fused import fused_heft_schedule
 from repro.workflow.generator import GroundTruth, build_workflow
 from repro.workflow.profiling import local_profiling
 from repro.workflow.simulator import execute_adaptive, execute_schedule
@@ -267,7 +267,7 @@ def test_adaptive_recovers_makespan_under_degraded_nodes():
     pred_rt = lambda u, n: lot.predict(dag.tasks[u].task_name,
                                        dag.tasks[u].input_gb,
                                        benches[n.name])[0]
-    static = execute_schedule(dag, heft_schedule(dag, nodes, pred_rt),
+    static = execute_schedule(dag, fused_heft_schedule(dag, nodes, pred_rt),
                               nodes, true_rt)
     online = OnlinePredictor(lot, benches=benches)
     planner = OnlineReschedulingPlanner(dag, nodes, online, benches=benches)
@@ -281,7 +281,7 @@ def test_on_complete_hook_sees_every_completion():
     nodes = list(TARGET_MACHINES)
     true_rt = lambda u, n: gt.runtime(dag.tasks[u].task_name,
                                       dag.tasks[u].input_gb, n, u)
-    sched = heft_schedule(dag, nodes, true_rt)
+    sched = fused_heft_schedule(dag, nodes, true_rt)
     seen = []
     execute_schedule(dag, sched, nodes, true_rt,
                      on_complete=lambda rec, state: seen.append(rec.uid))

@@ -12,7 +12,7 @@ from repro.core.traces import (PredictionRow, TraceRow, read_traces,
                                write_csv)
 from repro.core.downsample import partition_sizes, validate_partitions
 from repro.sched.cluster import LOCAL, TARGET_MACHINES
-from repro.sched.heft import heft_schedule
+from repro.sched.fused import fused_heft_schedule
 from repro.workflow.generator import GroundTruth, build_workflow
 from repro.workflow.profiling import local_profiling
 from repro.workflow.simulator import execute_schedule
@@ -53,9 +53,9 @@ def test_full_pipeline_beats_baselines_end_to_end():
     def pred_rt(u, n):
         t = dag.tasks[u]
         return lot.predict(t.task_name, t.input_gb, benches[n.name])[0]
-    ms_pred = execute_schedule(dag, heft_schedule(dag, nodes, pred_rt),
+    ms_pred = execute_schedule(dag, fused_heft_schedule(dag, nodes, pred_rt),
                                nodes, true_rt).makespan
-    ms_true = execute_schedule(dag, heft_schedule(dag, nodes, true_rt),
+    ms_true = execute_schedule(dag, fused_heft_schedule(dag, nodes, true_rt),
                                nodes, true_rt).makespan
     assert ms_pred <= 1.25 * ms_true       # near-optimal (paper: ~1.03-1.05)
 

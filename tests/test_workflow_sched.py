@@ -8,7 +8,8 @@ from repro.sched.cluster import PAPER_MACHINES, TARGET_MACHINES
 from repro.sched.cost import _billed_hours, cost_deviation_pct
 from repro.sched.elastic import (checkpoint_every_n_steps, choose_workers,
                                  expected_waste_fraction, young_daly_interval_s)
-from repro.sched.heft import comm_seconds, heft_schedule
+from repro.sched.fused import fused_heft_schedule
+from repro.sched.heft import comm_seconds
 from repro.sched.plane import RuntimeDist, TaskDistribution
 from repro.sched.straggler import (decide_speculation, normal_quantile,
                                    straggler_threshold)
@@ -63,7 +64,7 @@ def test_heft_respects_dependencies_and_uses_fast_node():
     nodes = [PAPER_MACHINES["A1"], PAPER_MACHINES["C2"]]
     rt = {"A1": 100.0, "C2": 10.0}
 
-    sched = heft_schedule(dag, nodes, lambda u, n: rt[n.name])
+    sched = fused_heft_schedule(dag, nodes, lambda u, n: rt[n.name])
     for uid, t in dag.tasks.items():
         s, f = sched.est[uid]
         for d in t.deps:
@@ -78,7 +79,7 @@ def test_simulated_makespan_at_least_critical_path():
     nodes = list(TARGET_MACHINES)
     true_rt = lambda u, n: gt.runtime(dag.tasks[u].task_name,
                                       dag.tasks[u].input_gb, n, u)
-    sched = heft_schedule(dag, nodes, true_rt)
+    sched = fused_heft_schedule(dag, nodes, true_rt)
     res = execute_schedule(dag, sched, nodes, true_rt)
     best_each = {u: min(true_rt(u, n) for n in nodes) for u in dag.tasks}
     assert res.makespan >= dag.critical_path_length(best_each) - 1e-6
@@ -92,7 +93,7 @@ def test_simulator_failure_increases_makespan():
     nodes = list(TARGET_MACHINES)
     true_rt = lambda u, n: gt.runtime(dag.tasks[u].task_name,
                                       dag.tasks[u].input_gb, n, u)
-    sched = heft_schedule(dag, nodes, true_rt)
+    sched = fused_heft_schedule(dag, nodes, true_rt)
     base = execute_schedule(dag, sched, nodes, true_rt).makespan
     mid = base / 2
     failed = execute_schedule(dag, sched, nodes, true_rt,
@@ -109,7 +110,7 @@ def test_property_random_clusters_schedule_all_tasks(seed):
     nodes = random_cluster(rng, list(TARGET_MACHINES), n_nodes=5)
     true_rt = lambda u, n: gt.runtime(dag.tasks[u].task_name,
                                       dag.tasks[u].input_gb, n, u)
-    sched = heft_schedule(dag, nodes, true_rt)
+    sched = fused_heft_schedule(dag, nodes, true_rt)
     res = execute_schedule(dag, sched, nodes, true_rt)
     assert len(res.records) == len(dag.tasks)
     assert res.makespan > 0
