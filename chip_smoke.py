@@ -15,9 +15,9 @@ plain float64 reference:
            carries all 16,384 queries.  Mean and std against
            bayes.predict_blr_np on the same gathered rows.
   refresh  one FleetRefresher.refresh over >= 4,096 due tasks of >= 64
-           tenants: one bayes_fit_ragged dispatch.  Predictive mean and
-           std of the fit against a float64 NumPy evidence fit of the same
-           buffers.
+           tenants: one call of the jitted fit entry (ops.bayes_fit) on
+           one host-packed buffer.  Predictive mean and std of the fit
+           against a float64 NumPy evidence fit of the same buffers.
   ingest   2,000 records over 4 tenants through observe_many (host
            float64 by contract): state digests equal the scalar chain.
   replan   FusedPlane.schedule on a 1000-task x 100-node DAG (the jit
@@ -61,7 +61,7 @@ from benchmarks.replan_latency import (_build,  # noqa: E402
                                        reference_schedule)
 from repro.core import bayes  # noqa: E402
 from repro.kernels import ops  # noqa: E402
-from repro.kernels.bayes_fit import (bayes_fit_ragged,  # noqa: E402
+from repro.kernels.bayes_fit import (pack_fit_planes,  # noqa: E402
                                      pack_predict_planes, pad_ragged)
 from repro.online import (FleetRefresher, OnlinePredictor,  # noqa: E402
                           PredictionQuery, PredictionService, RefreshPolicy,
@@ -371,8 +371,9 @@ def phase_refresh(fleet: Fleet, sizes: Sizes, seed: int) -> dict:
     y_sd = np.broadcast_to(ref["y_sd"][:, None], x.shape)
     err = max(_rel_err(pg[0][sel], pr[0][sel], y_sd[sel]),
               _rel_err(pg[1][sel], pr[1][sel]))
-    pallas = _lowers_to_pallas(bayes_fit_ragged, jnp.asarray(x),
-                               jnp.asarray(y), jnp.asarray(m))
+    pallas = _lowers_to_pallas(
+        functools.partial(ops.bayes_fit, impl="auto"),
+        jnp.asarray(pack_fit_planes(x, y, m)))
     ok = all(r.n_dispatches == 1 and r.n_stale == 0
              and r.n_tasks >= sizes.refresh_tasks
              and r.n_tenants >= sizes.refresh_tenants

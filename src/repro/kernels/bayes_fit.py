@@ -92,15 +92,16 @@ def _bayes_kernel(x_ref, y_ref, m_ref, mu_ref, sig_ref, hyp_ref, stat_ref):
 
 def bayes_fit(x: jnp.ndarray, y: jnp.ndarray, mask: jnp.ndarray, *,
               block_tasks: int = DEFAULT_BLOCK_TASKS,
-              interpret: bool = False) -> dict:
-    """x, y, mask: (T, N) -> posterior dict matching core.bayes.fit_blr
-    (leaves stacked over T)."""
+              interpret: bool = False) -> tuple:
+    """x, y, mask: (T, N) -> the kernel's four float32 outputs (T, 2) mu,
+    (T, 4) sigma, (T, 2) (alpha, beta_prec) and (T, 5) (x_mu, x_sd, y_mu,
+    y_sd, n): the columns of FIT_LAYOUT, in order."""
     t, n = x.shape
     block_tasks = min(block_tasks, t)
     assert t % block_tasks == 0, (t, block_tasks)
     grid = (t // block_tasks,)
     in_spec = pl.BlockSpec((block_tasks, n), lambda i: (i, 0))
-    mu, sig, hyp, stat = pl.pallas_call(
+    return tuple(pl.pallas_call(
         _bayes_kernel,
         grid=grid,
         in_specs=[in_spec, in_spec, in_spec],
@@ -113,11 +114,49 @@ def bayes_fit(x: jnp.ndarray, y: jnp.ndarray, mask: jnp.ndarray, *,
                    jax.ShapeDtypeStruct((t, 2), jnp.float32),
                    jax.ShapeDtypeStruct((t, 5), jnp.float32)],
         interpret=interpret,
-    )(x.astype(jnp.float32), y.astype(jnp.float32), mask.astype(jnp.float32))
-    return {"mu": mu, "sigma": sig.reshape(t, 2, 2),
-            "alpha": hyp[:, 0], "beta_prec": hyp[:, 1],
-            "x_mu": stat[:, 0], "x_sd": stat[:, 1],
-            "y_mu": stat[:, 2], "y_sd": stat[:, 3], "n": stat[:, 4]}
+    )(x.astype(jnp.float32), y.astype(jnp.float32), mask.astype(jnp.float32)))
+
+
+# The fit's packed result: per task one float32 row of FIT_COLS columns,
+# the posterior leaves side by side in this order (sigma row-major).
+FIT_LAYOUT = (("mu", 2), ("sigma", 4), ("alpha", 1), ("beta_prec", 1),
+              ("x_mu", 1), ("x_sd", 1), ("y_mu", 1), ("y_sd", 1), ("n", 1))
+FIT_COLS = sum(k for _, k in FIT_LAYOUT)
+
+
+def fit_columns(post: dict) -> jnp.ndarray:
+    """Posterior leaves stacked over T -> (T, FIT_COLS) float32, the
+    layout of the kernel's concatenated outputs (traceable)."""
+    t = post["mu"].shape[0]
+    return jnp.concatenate([jnp.reshape(post[k], (t, w)).astype(jnp.float32)
+                            for k, w in FIT_LAYOUT], axis=1)
+
+
+def pack_fit_planes(x: np.ndarray, y: np.ndarray, mask: np.ndarray,
+                    block_tasks: int = DEFAULT_BLOCK_TASKS) -> np.ndarray:
+    """Host side of the fleet fit: padded (T, N) x, y, mask (`pad_ragged`)
+    -> one fresh float32 (3, Tp, N) buffer, ready for one transfer.  T is
+    padded up to a `block_tasks` multiple with fully masked rows, which the
+    kernel's masked reductions leave exact, so a pass of any task count up
+    to the bucket reuses one compiled program; N is already bucketed."""
+    t, n = np.shape(x)
+    tp = -(-max(t, 1) // block_tasks) * block_tasks
+    buf = np.empty((3, tp, n), np.float32)
+    buf[0, :t], buf[1, :t], buf[2, :t] = x, y, mask
+    buf[:, t:] = 0.0
+    return buf
+
+
+def unpack_fit(out: np.ndarray, t: int) -> dict:
+    """The read-back (Tp, FIT_COLS) result -> the posterior dict of its
+    first `t` rows, float64 NumPy leaves (sigma (t, 2, 2))."""
+    rows = np.asarray(out[:t], np.float64)
+    post, c = {}, 0
+    for k, w in FIT_LAYOUT:
+        post[k] = rows[:, c] if w == 1 else rows[:, c:c + w]
+        c += w
+    post["sigma"] = post["sigma"].reshape(t, 2, 2)
+    return post
 
 
 def pad_ragged(xs, ys, min_cols: int = 2, col_bucket: int = 64):
@@ -149,25 +188,6 @@ def pad_ragged(xs, ys, min_cols: int = 2, col_bucket: int = 64):
         y[i, :k] = np.asarray(yi, np.float32)
         m[i, :k] = 1.0
     return x, y, m
-
-
-def bayes_fit_ragged(x: jnp.ndarray, y: jnp.ndarray, mask: jnp.ndarray, *,
-                     block_tasks: int = DEFAULT_BLOCK_TASKS,
-                     interpret: bool = False) -> dict:
-    """`bayes_fit` for any task count: rows already carry per-row masks
-    (pad_ragged); the task dimension is padded to a grid-block multiple
-    with fully-masked rows so a fleet refresh of, say, 130 due tasks still
-    costs ONE pallas_call, then the padding rows are sliced off."""
-    t = x.shape[0]
-    bt = min(block_tasks, t)
-    tp = -(-t // bt) * bt
-    if tp != t:
-        pad = ((0, tp - t), (0, 0))
-        x = jnp.pad(x, pad)
-        y = jnp.pad(y, pad)
-        mask = jnp.pad(mask, pad)
-    post = bayes_fit(x, y, mask, block_tasks=bt, interpret=interpret)
-    return {k: v[:t] for k, v in post.items()}
 
 
 # ---------------------------------------------------------------------------

@@ -1,10 +1,12 @@
 """jit'd public wrappers for the Pallas kernels, with platform dispatch:
 TPU -> compiled kernel; CPU -> interpret mode (tests) or the jnp reference
 (production fallback).  The serving stack calls `bayes_predict` (through
-`store.compute.predict_stacked`); `flash_attention`, `rglru_scan` and
-`bayes_fit` are reached by tests only.  The decision plane's sweep
-(`kernels.decision_plane`) carries loop state and keeps its own jit entry
-points."""
+`store.compute.predict_stacked`) and the maintenance plane's fleet fit
+calls `bayes_fit` (through `store.compute.fit_stacked`): each is one
+jitted program fed by one host-packed buffer, so no Pallas call is ever
+traced outside a jit.  `flash_attention` and `rglru_scan` are reached by
+tests only.  The decision plane's sweep (`kernels.decision_plane`)
+carries loop state and keeps its own jit entry points."""
 from __future__ import annotations
 
 import functools
@@ -16,7 +18,7 @@ import numpy as np
 from repro import obs
 from repro.kernels import ref
 from repro.kernels.bayes_fit import bayes_fit as _bayes_fit_pallas
-from repro.kernels.bayes_fit import (bayes_predict_planes,
+from repro.kernels.bayes_fit import (bayes_predict_planes, fit_columns,
                                      pack_predict_planes)
 from repro.kernels.flash_attention import flash_attention as _flash_pallas
 from repro.kernels.rglru_scan import rglru_scan as _rglru_pallas
@@ -48,12 +50,21 @@ def rglru_scan(a, gx, h0, *, impl: str = "auto"):
 
 
 @functools.partial(jax.jit, static_argnames=("impl",))
-def bayes_fit(x, y, mask, *, impl: str = "auto"):
+def bayes_fit(planes, *, impl: str = "auto"):
+    """Packed float32 (3, Tp, N) x, y, mask planes (`pack_fit_planes`),
+    Tp a DEFAULT_BLOCK_TASKS multiple -> (Tp, FIT_COLS) float32 posterior
+    columns (FIT_LAYOUT).  The kernel's four outputs are concatenated after
+    the Pallas call, inside this program, so the call keeps its own output
+    shapes.  The count below runs only while the entry is traced: after
+    warm-up, a pass that adds to it compiled inside the window."""
+    obs.count("lotaru.compute.fit_traces", 1)
+    x, y, mask = planes[0], planes[1], planes[2]
     if impl == "pallas" or (impl == "auto" and _on_tpu()):
-        return _bayes_fit_pallas(x, y, mask)
+        return jnp.concatenate(_bayes_fit_pallas(x, y, mask), axis=1)
     if impl == "interpret":
-        return _bayes_fit_pallas(x, y, mask, interpret=True)
-    return ref.bayes_fit_ref(x, y, mask)
+        return jnp.concatenate(
+            _bayes_fit_pallas(x, y, mask, interpret=True), axis=1)
+    return fit_columns(ref.bayes_fit_ref(x, y, mask))
 
 
 @functools.partial(jax.jit, static_argnames=("impl",))

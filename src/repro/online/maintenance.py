@@ -17,12 +17,14 @@ This module closes that loop across the whole store:
   * `FleetRefresher` gathers the ragged observation buffers of every due
     task across every tenant bound to one `PosteriorStore`, re-runs the
     evidence fixed point for all of them in ONE padded/masked batched fit
-    dispatch (`store.compute.fit_stacked`: Pallas kernel on TPU, jit'd vmap
-    elsewhere), moment-matches the refreshed posteriors back into the
-    streaming NIG states in one stacked lift (each task taken up by
-    `OnlinePredictor.apply_refresh`), and publishes every rewritten row,
-    exported from the predictors' states in one stacked export, through
-    the store in a single copy-on-write generation bump (`put_stacked`).
+    dispatch (`store.compute.fit_stacked`: on TPU one host-packed transfer,
+    one call of the jitted Pallas fit compiled once per shape bucket, and
+    one readback; jit'd vmap elsewhere), moment-matches the refreshed
+    posteriors back into the streaming NIG states in one stacked lift
+    (each task taken up by `OnlinePredictor.apply_refresh`), and
+    publishes every rewritten row, exported from the predictors' states in
+    one stacked export, through the store in a single copy-on-write
+    generation bump (`put_stacked`).
 
 The refresh is out-of-band by construction: the expensive fit runs with no
 locks held (a fit that races a concurrent observe() is rejected per task by

@@ -51,22 +51,35 @@ def fit_stacked(x: np.ndarray, y: np.ndarray, mask: np.ndarray,
 
     This is the fit-side sibling of `predict_stacked`, shared by the
     posterior maintenance plane (fleet-wide evidence refresh) and any bulk
-    re-fit: TPU gets the fused Pallas kernel with ragged row padding
-    (`kernels.bayes_fit.bayes_fit_ragged`), everywhere else the jit'd vmap
-    of `core.bayes.fit_blr` — either way a fleet of task models re-fits in
-    a single dispatch instead of one fixed-point solve per task."""
+    re-fit.  TPU: the buffers are packed on the host into one float32
+    (3, Tp, N) buffer, T bucketed to the kernel's block
+    (`kernels.bayes_fit.pack_fit_planes`); then one transfer, one call of
+    the jitted `kernels.ops.bayes_fit` around the fused Pallas kernel, and
+    one readback of its (Tp, FIT_COLS) result, split into leaves on the
+    host.  Everywhere else the jit'd vmap of `core.bayes.fit_blr`.  Either
+    way a fleet of task models re-fits in a single dispatch instead of one
+    fixed-point solve per task."""
     from repro.core import bayes
     from repro.kernels import ops
-    import jax.numpy as jnp
-    xj = jnp.asarray(x, jnp.float32)
-    yj = jnp.asarray(y, jnp.float32)
-    mj = jnp.asarray(mask, jnp.float32)
-    if impl in ("pallas", "interpret") or (impl == "auto" and ops._on_tpu()):
-        from repro.kernels.bayes_fit import bayes_fit_ragged
-        post = bayes_fit_ragged(xj, yj, mj, interpret=(impl == "interpret"))
-    else:
-        post = bayes.fit_blr_batch(xj, yj, mj)
-    return {k: np.asarray(v, np.float64) for k, v in post.items()}
+    with obs.span("lotaru.compute.fit"):
+        if not (impl in ("pallas", "interpret")
+                or (impl == "auto" and ops._on_tpu())):
+            import jax.numpy as jnp
+            post = bayes.fit_blr_batch(jnp.asarray(x, jnp.float32),
+                                       jnp.asarray(y, jnp.float32),
+                                       jnp.asarray(mask, jnp.float32))
+            return {k: np.asarray(v, np.float64) for k, v in post.items()}
+        import jax
+        from repro.kernels.bayes_fit import pack_fit_planes, unpack_fit
+        with obs.span("lotaru.compute.fit_pad"):
+            planes = pack_fit_planes(x, y, mask)
+        out = ops.bayes_fit(jax.device_put(planes), impl=impl)
+        with obs.span("lotaru.compute.fit_readback"):
+            host = np.asarray(out)
+        if obs.enabled():
+            obs.count("lotaru.compute.fit_h2d_bytes", planes.nbytes)
+            obs.count("lotaru.compute.fit_d2h_bytes", host.nbytes)
+        return unpack_fit(host, np.shape(x)[0])
 
 
 def scale(mean: np.ndarray, std: np.ndarray, factors: np.ndarray

@@ -66,8 +66,9 @@ def test_bayes_fit_kernel_sweep(t, n):
     y = (b + a * x + rng.normal(0, 0.05, (t, n))).astype(np.float32)
     m = np.ones((t, n), np.float32)
     m[:, n - 2:] = 0.0
-    out = ops.bayes_fit(jnp.asarray(x), jnp.asarray(y), jnp.asarray(m),
-                        impl="interpret")
+    from repro.kernels.bayes_fit import pack_fit_planes, unpack_fit
+    out = unpack_fit(np.asarray(ops.bayes_fit(
+        jnp.asarray(pack_fit_planes(x, y, m)), impl="interpret")), t)
     exp = ref.bayes_fit_ref(jnp.asarray(x), jnp.asarray(y), jnp.asarray(m))
     for key in ("mu", "sigma", "alpha", "beta_prec", "x_mu", "y_sd"):
         np.testing.assert_allclose(np.asarray(out[key]), np.asarray(exp[key]),

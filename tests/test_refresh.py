@@ -272,11 +272,13 @@ def test_service_refresh_noop_for_static_predictor():
 
 
 # --- ragged batched fit kernel --------------------------------------------------
-def test_bayes_fit_ragged_pads_rows_and_tasks():
+def test_bayes_fit_pads_rows_and_tasks():
     """per-row masks + task-dimension padding: a task count that is not a
-    block multiple still fits in one pallas_call, exactly."""
-    import jax.numpy as jnp
-    from repro.kernels.bayes_fit import bayes_fit_ragged, pad_ragged
+    block multiple still fits in one call of the jitted entry, exactly."""
+    import jax
+    from repro.kernels import ops
+    from repro.kernels.bayes_fit import (FIT_COLS, pack_fit_planes,
+                                         pad_ragged, unpack_fit)
     rng = np.random.default_rng(7)
     xs_list, ys_list = [], []
     for i in range(6):                               # ragged lengths 3..14
@@ -287,8 +289,11 @@ def test_bayes_fit_ragged_pads_rows_and_tasks():
     x, y, m = pad_ragged(xs_list, ys_list, col_bucket=1)
     assert x.shape == (6, 13)                        # unbucketed: exact max
     assert pad_ragged(xs_list, ys_list)[0].shape == (6, 64)   # jit bucket
-    post = bayes_fit_ragged(jnp.asarray(x), jnp.asarray(y), jnp.asarray(m),
-                            block_tasks=4, interpret=True)   # 6 -> pad to 8
+    planes = pack_fit_planes(x, y, m, block_tasks=4)  # 6 -> pad to 8
+    assert planes.shape == (3, 8, 13)
+    out = np.asarray(ops.bayes_fit(jax.device_put(planes), impl="interpret"))
+    assert out.shape == (8, FIT_COLS)
+    post = unpack_fit(out, 6)
     assert post["mu"].shape == (6, 2)
     for i in range(6):
         ref = bayes.fit_blr(xs_list[i].astype(np.float32),

@@ -15,7 +15,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import bayes_fit as bf
 from repro.kernels import decision_plane as dp
-from repro.kernels.bayes_fit import bayes_fit_ragged, bayes_predict
+from repro.kernels.bayes_fit import bayes_predict
 
 
 @pytest.fixture(scope="module")
@@ -74,11 +74,32 @@ def test_packed_predict_entry_is_one_named_kernel(one_chip, rows):
     assert "fusion(" not in entry
 
 
+def _fit_entry_text(tasks, sharding) -> str:
+    """The jitted fit entry compiled for `tasks` due tasks of 64 columns,
+    packed as `pack_fit_planes` packs them."""
+    from repro.kernels import ops
+    tp = -(-tasks // bf.DEFAULT_BLOCK_TASKS) * bf.DEFAULT_BLOCK_TASKS
+    planes = _spec((3, tp, 64), jnp.float32, sharding)
+    return ops.bayes_fit.lower(planes, impl="pallas").compile().as_text()
+
+
 @pytest.mark.parametrize("tasks", [4096, 130])
-def test_bayes_fit_ragged_compiles(one_chip, tasks):
-    arg = _spec((tasks, 64), jnp.float32, one_chip)
-    text = _compile_text(bayes_fit_ragged, arg, arg, arg)
-    assert "tpu_custom_call" in text
+def test_bayes_fit_compiles(one_chip, tasks):
+    assert "tpu_custom_call" in _fit_entry_text(tasks, one_chip)
+
+
+@pytest.mark.parametrize("tasks", [4096, 130])
+def test_packed_fit_entry_is_one_fit_kernel(one_chip, tasks):
+    """The jitted fit entry compiles to one Pallas call, and the call's
+    instruction keeps the kernel's four output shapes: a device trace
+    finds the fit kernel by them (`bench/layers/_shared.is_fit_kernel`)."""
+    from bench.layers._shared import is_fit_kernel, is_predict_kernel
+    text = _fit_entry_text(tasks, one_chip)
+    entry = text[text.index("ENTRY"):]
+    calls = [ln.strip() for ln in entry.splitlines()
+             if "tpu_custom_call" in ln]
+    assert len(calls) == 1
+    assert is_fit_kernel(calls[0]) and not is_predict_kernel(calls[0])
 
 
 def test_eft_sweep_x64_compiles_at_1024x128(one_chip):
